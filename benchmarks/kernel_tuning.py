@@ -125,6 +125,8 @@ if __name__ == "__main__":
                     help="fail (exit nonzero) unless tuned <= default for "
                          "the decode cell — the nightly serve-hot-path gate")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(smoke=args.smoke, budget=args.budget, reps=args.reps,
          store_path=args.store, seed=args.seed,
          assert_decode_win=args.assert_decode_win)

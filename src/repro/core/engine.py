@@ -187,6 +187,13 @@ class ParallelTuningEngine:
         self.max_in_flight = max(max_in_flight or max(self.workers,
                                                       self.batch_size), 1)
         self.backend = backend
+        if backend == "process" and self.workers > 1:
+            import jax
+            if jax.default_backend() == "tpu":
+                raise RuntimeError(
+                    "backend='process' starts worker processes that would "
+                    "each open this host's TPU, which belongs to one process "
+                    "at a time; use backend='thread' on a TPU host")
         self.max_total_calls = max_total_calls
         self.checkpoint_path = checkpoint_path
         # shared record store (repro.store): journal persistence + transfer.

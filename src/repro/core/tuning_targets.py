@@ -266,6 +266,9 @@ class DryRunObjective(Objective):
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(self.root, "src")
         env.pop("XLA_FLAGS", None)
+        # the dry-run compiles for placeholder CPU devices; on a TPU host the
+        # child must not open the chip (the parent may hold it)
+        env["JAX_PLATFORMS"] = "cpu"
         try:
             subprocess.run(cmd, cwd=self.root, env=env, timeout=self.timeout_s,
                            capture_output=True, text=True)

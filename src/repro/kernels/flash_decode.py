@@ -6,10 +6,11 @@ to tile, which makes the natural parallel axis the CACHE LENGTH. The kernel
 partitions the cache into ``num_splits`` independent ranges, each scanned
 in ``block_kv`` tiles with the online-softmax (m, l, acc) state held in
 VMEM, then a cross-split combine merges the per-split partials — the
-"flash-decode" decomposition. GQA is native: the grid iterates KV heads and
-each program holds that head's G = H/KV grouped query rows, so KV tiles are
-loaded ONCE per group instead of per query head (the GQA-expansion the
-prefill kernel needs would multiply decode HBM traffic by G).
+"flash-decode" decomposition. GQA is native: each program holds the cache
+tile of every KV head and, per head, that head's G = H/KV grouped query
+rows, so KV tiles are loaded ONCE per group instead of per query head (the
+GQA-expansion the prefill kernel needs would multiply decode HBM traffic by
+G).
 
 Validity (cache slots never written, slots beyond the current position,
 rolling-window eviction) enters as a precomputed additive f32 bias row
@@ -31,20 +32,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import vmem_tile_bytes
+
 COMBINE_STRATEGIES = ("jax", "kernel")
 
 
 def _decode_split_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_out_ref,
                          l_out_ref, m_ref, l_ref, acc_ref, *, steps: int,
-                         scale: float):
-    """One (batch, kv_head, split) program: scan this split's KV tiles with
-    online softmax, emit unnormalized (acc, m, l) partials for the combine.
+                         kv_heads: int, scale: float):
+    """One (batch, split) program: scan this split's KV tiles with online
+    softmax for every KV head, emit unnormalized (acc, m, l) partials for
+    the combine.
 
     Masked positions carry a -inf bias, so ``exp(s - m_safe)`` is exactly 0
     for them; an all-masked split keeps m = -inf / l = 0 and contributes
     nothing downstream.
     """
-    j = pl.program_id(3)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -52,57 +56,60 @@ def _decode_split_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_out_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                   # (G, hd)
-    k = k_ref[0, :, 0, :]                          # (bkv, hd)
-    v = v_ref[0, :, 0, :]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0][None, :]                   # 0 valid / -inf masked
-
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])               # exp(-inf - 0) == 0
-    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_new
+    bias = bias_ref[...]                           # (1, bkv) 0 / -inf
+    for h in range(kv_heads):                      # static: one head group
+        q = q_ref[h]                               # (G, hd)
+        k = k_ref[:, h, :]                         # (bkv, hd)
+        v = v_ref[:, h, :]
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = s + bias
+        m_prev = m_ref[h]                          # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - m_safe)                    # exp(-inf - 0) == 0
+        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(j == steps - 1)
     def _done():
-        o_ref[0, 0, 0] = acc_ref[...]
-        m_out_ref[0, 0, 0] = m_ref[:, 0]
-        l_out_ref[0, 0, 0] = l_ref[:, 0]
+        o_ref[...] = acc_ref[...]
+        m_out_ref[...] = m_ref[...]
+        l_out_ref[...] = l_ref[...]
 
 
 def _combine_partials_jnp(o_part, m_part, l_part):
     """Merge per-split (acc, m, l) into normalized attention output.
 
-    o_part (B,KV,S,G,hd) f32, m/l (B,KV,S,G) — the flash cross-block
+    o_part (B,S,KV,G,hd) f32, m/l (B,S,KV,G,1) — the flash cross-block
     correction applied once across splits: weight each split by
     exp(m_i - max_i m_i), then normalize by the merged l.
     """
-    m_tot = m_part.max(axis=2)                                 # (B,KV,G)
+    m_tot = m_part.max(axis=1)                                 # (B,KV,G,1)
     m_safe = jnp.where(jnp.isfinite(m_tot), m_tot, 0.0)
     w = jnp.where(jnp.isfinite(m_part),
-                  jnp.exp(m_part - m_safe[:, :, None, :]), 0.0)
-    l_tot = jnp.sum(w * l_part, axis=2)                        # (B,KV,G)
-    o = jnp.sum(w[..., None] * o_part, axis=2)                 # (B,KV,G,hd)
-    return o / jnp.maximum(l_tot, 1e-30)[..., None]
+                  jnp.exp(m_part - m_safe[:, None]), 0.0)
+    l_tot = jnp.sum(w * l_part, axis=1)                        # (B,KV,G,1)
+    o = jnp.sum(w * o_part, axis=1)                            # (B,KV,G,hd)
+    return o / jnp.maximum(l_tot, 1e-30)
 
 
-def _decode_combine_kernel(o_ref, m_ref, l_ref, out_ref):
-    """One (batch, kv_head) program folding all splits of one head group."""
-    o = o_ref[0, 0]                                # (S, G, hd) f32
-    m = m_ref[0, 0]                                # (S, G)
-    l = l_ref[0, 0]
-    m_tot = m.max(axis=0)                          # (G,)
+def _decode_combine_kernel(o_ref, m_ref, l_ref, out_ref, *, num_splits: int):
+    """One batch row's program folding all splits of every head group."""
+    m_tot = m_ref[0]                               # (KV, G, 1)
+    for s in range(1, num_splits):
+        m_tot = jnp.maximum(m_tot, m_ref[s])
     m_safe = jnp.where(jnp.isfinite(m_tot), m_tot, 0.0)
-    w = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe[None, :]), 0.0)
-    l_tot = jnp.sum(w * l, axis=0)                 # (G,)
-    merged = jnp.sum(w[..., None] * o, axis=0)     # (G, hd)
-    out_ref[0] = (merged / jnp.maximum(l_tot, 1e-30)[:, None]
-                  ).astype(out_ref.dtype)
+    l_tot = jnp.zeros_like(m_tot)
+    merged = jnp.zeros(out_ref.shape, jnp.float32)  # (KV, G, hd)
+    for s in range(num_splits):
+        m = m_ref[s]
+        w = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        l_tot = l_tot + w * l_ref[s]
+        merged = merged + w * o_ref[s]
+    out_ref[...] = (merged / jnp.maximum(l_tot, 1e-30)).astype(out_ref.dtype)
 
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -113,6 +120,11 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     (B, S, KV, hd) with S % (num_splits * block_kv) == 0 (the ops wrapper
     pads arbitrary capacities); bias (B, S) f32 additive validity mask
     (0 valid / -inf masked). Returns (B, H, hd) in q's dtype.
+
+    Every block spans whole trailing dims or a tiled slice of the cache
+    axis, the only layout the TPU compiler accepts here: a K/V block holds
+    all KV heads of ``block_kv`` slots (the kernel loops over heads), q is
+    viewed as (B, KV, G, hd) and the bias as (B, 1, S).
     """
     B, H, hd = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
@@ -123,66 +135,71 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     G = H // KV
     steps = S // (num_splits * block_kv)
     scale = 1.0 / (hd ** 0.5)
-    grid = (B, KV, num_splits, steps)
+    grid = (B, num_splits, steps)
 
     kw = {}
     if not interpret:
         kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    spec_q = pl.BlockSpec((1, G, hd), lambda b, k, s, j: (b, k, 0))
-    spec_kv = pl.BlockSpec((1, block_kv, 1, hd),
-                           lambda b, k, s, j: (b, s * steps + j, k, 0))
-    spec_bias = pl.BlockSpec((1, block_kv),
-                             lambda b, k, s, j: (b, s * steps + j))
-    spec_o = pl.BlockSpec((1, 1, 1, G, hd), lambda b, k, s, j: (b, k, s, 0, 0))
-    spec_ml = pl.BlockSpec((1, 1, 1, G), lambda b, k, s, j: (b, k, s, 0))
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    spec_q = pl.BlockSpec((None, KV, G, hd), lambda b, s, j: (b, 0, 0, 0))
+    spec_kv = pl.BlockSpec((None, block_kv, KV, hd),
+                           lambda b, s, j: (b, s * steps + j, 0, 0))
+    spec_bias = pl.BlockSpec((None, 1, block_kv),
+                             lambda b, s, j: (b, 0, s * steps + j))
+    spec_o = pl.BlockSpec((None, None, KV, G, hd),
+                          lambda b, s, j: (b, s, 0, 0, 0))
+    spec_ml = pl.BlockSpec((None, None, KV, G, 1),
+                           lambda b, s, j: (b, s, 0, 0, 0))
+    ml_shape = jax.ShapeDtypeStruct((B, num_splits, KV, G, 1), jnp.float32)
     o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_split_kernel, steps=steps, scale=scale),
+        functools.partial(_decode_split_kernel, steps=steps, kv_heads=KV,
+                          scale=scale),
         grid=grid,
         in_specs=[spec_q, spec_kv, spec_kv, spec_bias],
         out_specs=[spec_o, spec_ml, spec_ml],
         out_shape=[
-            jax.ShapeDtypeStruct((B, KV, num_splits, G, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, num_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, num_splits, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, num_splits, KV, G, hd), jnp.float32),
+            ml_shape, ml_shape,
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),       # m
-            pltpu.VMEM((G, 1), jnp.float32),       # l
-            pltpu.VMEM((G, hd), jnp.float32),      # acc
+            pltpu.VMEM((KV, G, 1), jnp.float32),       # m
+            pltpu.VMEM((KV, G, 1), jnp.float32),       # l
+            pltpu.VMEM((KV, G, hd), jnp.float32),      # acc
         ],
         interpret=interpret,
         **kw,
-    )(q, k_cache, v_cache, bias)
+    )(q.reshape(B, KV, G, hd), k_cache, v_cache, bias[:, None, :])
 
     if combine == "kernel":
         out = pl.pallas_call(
-            _decode_combine_kernel,
-            grid=(B, KV),
+            functools.partial(_decode_combine_kernel, num_splits=num_splits),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, 1, num_splits, G, hd),
-                             lambda b, k: (b, k, 0, 0, 0)),
-                pl.BlockSpec((1, 1, num_splits, G), lambda b, k: (b, k, 0, 0)),
-                pl.BlockSpec((1, 1, num_splits, G), lambda b, k: (b, k, 0, 0)),
+                pl.BlockSpec((None, num_splits, KV, G, hd),
+                             lambda b: (b, 0, 0, 0, 0)),
+                pl.BlockSpec((None, num_splits, KV, G, 1),
+                             lambda b: (b, 0, 0, 0, 0)),
+                pl.BlockSpec((None, num_splits, KV, G, 1),
+                             lambda b: (b, 0, 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, G, hd), lambda b, k: (b, k, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+            out_specs=pl.BlockSpec((None, KV, G, hd), lambda b: (b, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
             interpret=interpret,
         )(o_part, m_part, l_part)
-        return out
+        return out.reshape(B, H, hd)
     merged = _combine_partials_jnp(o_part, m_part, l_part)     # (B,KV,G,hd)
     return merged.reshape(B, H, hd).astype(q.dtype)
 
 
-def decode_vmem_bytes(block_kv: int, G: int, hd: int,
+def decode_vmem_bytes(block_kv: int, KV: int, G: int, hd: int,
                       dtype_bytes: int = 2) -> int:
-    """Split-kernel VMEM working set: K/V tiles + the head group's q rows,
-    f32 scores, (m, l, acc) state, bias row, and the partial outputs."""
-    kv = 2 * block_kv * hd * dtype_bytes
-    qrows = G * hd * dtype_bytes
-    scores = G * block_kv * 4
-    state = G * (hd + 2) * 4
-    bias = block_kv * 4
-    partials = G * (hd + 2) * 4
-    return kv + qrows + scores + state + bias + partials
+    """Split-kernel VMEM working set: double-buffered K/V blocks (all KV
+    heads of ``block_kv`` slots), q rows, bias row and partial outputs, the
+    (m, l, acc) scratch, and one head's f32 scores plus probabilities."""
+    kv = 2 * 2 * block_kv * vmem_tile_bytes(KV, hd, dtype_bytes)
+    qrows = 2 * KV * vmem_tile_bytes(G, hd, dtype_bytes)
+    bias = 2 * vmem_tile_bytes(1, block_kv, 4)
+    state = KV * (vmem_tile_bytes(G, hd, 4) + 2 * vmem_tile_bytes(G, 1, 4))
+    partials = 2 * state
+    scores = 2 * vmem_tile_bytes(G, block_kv, 4)
+    return kv + qrows + bias + state + partials + scores

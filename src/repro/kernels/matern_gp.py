@@ -24,6 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 SQRT3 = math.sqrt(3.0)
 SQRT5 = math.sqrt(5.0)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _matern(r, ell: float, nu: str):
@@ -46,12 +47,16 @@ def _gp_kernel(xc_ref, xo_ref, vinv_ref, w_ref, mask_ref,
     xc = xc_ref[...]                                  # (bn, d)
     xo = xo_ref[...]                                  # (T, d)
     mask = mask_ref[...]                              # (T, 1) 1.0/0.0
+    # full f32 matmuls: the MXU's default f32 precision (bf16 passes)
+    # costs ~1e-2 of the posterior variance, which 1 - sum(V^2) amplifies
     d2 = (jnp.sum(xo * xo, axis=1, keepdims=True)
           + jnp.sum(xc * xc, axis=1)[None, :]
-          - 2.0 * jnp.dot(xo, xc.T, preferred_element_type=jnp.float32))
+          - 2.0 * jnp.dot(xo, xc.T, precision=HIGHEST,
+                          preferred_element_type=jnp.float32))
     r = jnp.sqrt(jnp.maximum(d2, 0.0))
     K = _matern(r, ell, nu) * mask                    # (T, bn), padded rows 0
-    V = jnp.dot(vinv_ref[...], K, preferred_element_type=jnp.float32)
+    V = jnp.dot(vinv_ref[...], K, precision=HIGHEST,
+                preferred_element_type=jnp.float32)
     mean_ref[...] = (w_ref[...] * V).sum(axis=0, keepdims=True)   # (1, bn)
     var_ref[...] = jnp.maximum(1.0 - jnp.sum(V * V, axis=0, keepdims=True),
                                1e-12)

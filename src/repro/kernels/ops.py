@@ -65,10 +65,15 @@ def gemm_valid(cfg: Dict, dtype_bytes: int = 2,
                                              "interpret"))
 def flash_attention(q, k, v, block_q=512, block_kv=512, causal=True,
                     interpret=None):
+    """q, k, v (B, S, H, hd) -> (B, S, H, hd). The kernel streams a
+    head-major view, so each operand is transposed to (B, H, S, hd) and the
+    output back: one extra HBM round trip per tensor."""
     if interpret is None:
         interpret = _interpret_default()
-    return _fa.flash_attention(q, k, v, block_q=block_q, block_kv=block_kv,
-                               causal=causal, interpret=interpret)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = _fa.flash_attention(q, k, v, block_q=block_q, block_kv=block_kv,
+                              causal=causal, interpret=interpret)
+    return out.transpose(0, 2, 1, 3)
 
 
 def flash_config_space(S: int = 4096) -> SearchSpace:
@@ -134,9 +139,9 @@ def decode_config_space(S: int = 2048) -> SearchSpace:
     return SearchSpace(params, cons, name="pallas_flash_decode")
 
 
-def decode_valid(cfg: Dict, G: int = 1, hd: int = 128, dtype_bytes: int = 2,
-                 vmem_bytes: int = VMEM_BYTES) -> bool:
-    return _fd.decode_vmem_bytes(cfg["block_kv"], G, hd,
+def decode_valid(cfg: Dict, KV: int = 1, G: int = 1, hd: int = 128,
+                 dtype_bytes: int = 2, vmem_bytes: int = VMEM_BYTES) -> bool:
+    return _fd.decode_vmem_bytes(cfg["block_kv"], KV, G, hd,
                                  dtype_bytes) <= vmem_bytes
 
 

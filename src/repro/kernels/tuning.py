@@ -42,9 +42,11 @@ KERNEL_NAMES = ("gemm", "flash", "decode", "gp")
 
 
 def device_kind() -> str:
-    """Device context kernel timings are keyed under — a cpu-interpret
-    record must never resolve for a tpu deployment (and vice versa)."""
-    return jax.default_backend()
+    """Device context kernel timings are keyed under: the chip generation
+    as JAX names it (``"TPU v5 lite"``; ``"cpu"`` off-TPU), so a record
+    tuned on one generation — or in interpret mode — never resolves for
+    another."""
+    return jax.devices()[0].device_kind
 
 
 def kernel_cell_objective(kernel: str, shape_sig: str,
@@ -158,7 +160,7 @@ def decode_cell(B: int = 4, S: int = 2048, H: int = 8, KV: int = 2,
         # padding tiles any capacity, but splits past the cache are pure
         # combine overhead — the alignment face of the resource model
         covered = cfg["block_kv"] * (cfg["num_splits"] - 1) < S
-        return covered and ops.decode_valid(cfg, G, hd, dtype_bytes,
+        return covered and ops.decode_valid(cfg, KV, G, hd, dtype_bytes,
                                             vmem_bytes)
 
     return KernelCell(
@@ -221,8 +223,10 @@ class KernelObjective(Objective):
     configuration, journaled by the runner, skipped by the surrogate —
     instead of crashing the run. A config that passes the model but fails
     at execution (compiler rejection, interpret-mode assert) is likewise
-    caught and journaled invalid. ``vmem_bytes`` is injectable so tests can
-    shrink the budget and pin the invalid path without 16 MiB tiles.
+    caught and journaled invalid; its message stays in ``errors`` (space
+    index -> first line) so a caller can tell a refused tile from a broken
+    kernel. ``vmem_bytes`` is injectable so tests can shrink the budget and
+    pin the invalid path without 16 MiB tiles.
     """
 
     def __init__(self, cell: KernelCell, *, reps: int = 3, warmup: int = 1,
@@ -235,6 +239,7 @@ class KernelObjective(Objective):
         self.warmup = max(int(warmup), 1)
         self.vmem_bytes = int(vmem_bytes)
         self.verbose = verbose
+        self.errors: Dict[int, str] = {}
 
     def __call__(self, idx: int) -> float:
         cfg = self.space.config(int(idx))
@@ -251,8 +256,12 @@ class KernelObjective(Objective):
                 jax.block_until_ready(self.cell.run(cfg))
                 best = min(best, time.perf_counter() - t0)
         except Exception as e:                    # runtime-discovered invalid
+            lines = str(e).strip().splitlines()
+            self.errors[int(idx)] = (f"{type(e).__name__}: "
+                                     f"{lines[0] if lines else ''}")
             if self.verbose:
-                print(f"  [kernel-tune] {cfg} -> INVALID ({type(e).__name__})")
+                print(f"  [kernel-tune] {cfg} -> INVALID "
+                      f"({self.errors[int(idx)]})")
             return math.nan
         if self.verbose:
             print(f"  [kernel-tune] {cfg} -> {best*1e3:.3f} ms")
@@ -265,16 +274,21 @@ class KernelObjective(Objective):
 def run_kernel_tuning(cell: KernelCell, store=None, *, budget: int = 12,
                       init: int = 4, seed: int = 0, reps: int = 3,
                       vmem_bytes: int = VMEM_BYTES, warm_start: bool = True,
-                      device: Optional[str] = None, verbose: bool = False):
+                      device: Optional[str] = None, verbose: bool = False,
+                      gp_backend: str = "numpy",
+                      objective: Optional[KernelObjective] = None):
     """Tune one kernel cell with the standard BO engine, journaling into the
     shared store under the cell's ``kernel[...]`` fingerprint. Returns the
-    engine's TuneResult."""
+    engine's TuneResult. ``gp_backend`` is the BO surrogate's posterior
+    backend (``"pallas"`` scores candidates with the matern_gp kernel);
+    pass a prebuilt ``objective`` to read its ``errors`` after the run."""
     from repro.core.runner import run_strategy
     from repro.core.strategies.bo import BOConfig, BOStrategy
-    obj = KernelObjective(cell, reps=reps, vmem_bytes=vmem_bytes,
-                          device=device, verbose=verbose)
+    obj = objective or KernelObjective(cell, reps=reps, vmem_bytes=vmem_bytes,
+                                       device=device, verbose=verbose)
     n_init = min(init, budget)
-    strat = BOStrategy(BOConfig(initial_samples=n_init))
+    strat = BOStrategy(BOConfig(initial_samples=n_init,
+                                gp_backend=gp_backend))
     run_id = f"kernel_{cell.kernel}_{cell.shape_sig}-s{seed}"
     return run_strategy(strat, obj, budget=budget, seed=seed, store=store,
                         run_id=run_id, warm_start=warm_start)
@@ -362,7 +376,7 @@ def decode_kernel_config_from_store(store, *, cache_cap: int, H: int, KV: int,
     if bkv * (ns - 1) >= cache_cap:
         return None             # tuned splits overhang this server's cache
     G = H // max(KV, 1)
-    if not ops.decode_valid({"block_kv": bkv}, G, hd):
+    if not ops.decode_valid({"block_kv": bkv}, KV, G, hd):
         return None
     base = base if base is not None else KernelConfig()
     return base.replace(use_decode=True, decode_block_kv=bkv,

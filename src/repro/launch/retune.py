@@ -237,6 +237,8 @@ def main() -> None:
                          "proc-<pid>); name each daemon of a fleet")
     args = ap.parse_args()
     from repro.core.strategies import make_strategy
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     daemon = RetuneDaemon(args.store,
                           strategy_factory=lambda: make_strategy(
                               args.strategy),

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get_arch, smoke_config
 from repro.kernels.cache import CompiledKernelCache, config_key
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.params import init_params
 from repro.models.stepfn import make_decode_step, make_prefill_step
 from repro.parallel.sharding import ParallelConfig, ShardCtx
@@ -124,6 +125,16 @@ class DecodeServer:
         self.kernel_swaps += 1
 
     @property
+    def prefill_dispatch(self) -> str:
+        """``"pallas"`` when prefill attention runs the Pallas flash kernel
+        at this server's prompt length, ``"jax"`` otherwise."""
+        from repro.models.layers import _pallas_flash_ok
+        hd = self.cfg.resolved_head_dim
+        return ("pallas" if _pallas_flash_ok(self.prompt_len, hd, hd, None,
+                                             self.pcfg.kernel)
+                else "jax")
+
+    @property
     def decode_dispatch(self) -> str:
         """Which implementation the next decode step's attention runs on —
         ``"pallas"`` when the flash-decode dispatch gate is open, ``"jax"``
@@ -148,6 +159,26 @@ class DecodeServer:
                 self.key, (B, self.prompt_len), 0, cfg.vocab_size)}
         return batch
 
+    def step_batch(self, toks):
+        """Decode-step input for the tokens ``toks`` (B,) just generated."""
+        if self.cfg.frontend == "embeddings":
+            emb = self.params["lm_head"]["w"][:, toks].T[:, None, :] \
+                .astype(jnp.dtype(self.cfg.dtype))
+            return {"frame_embeddings": emb}
+        return {"tokens": toks[:, None]}
+
+    def warmup(self, batch) -> float:
+        """Compile the prefill and decode steps by running each once on
+        throwaway outputs (held state is untouched), so the timed steps
+        that follow run compiled code. Returns seconds."""
+        t0 = time.perf_counter()
+        logits, cache = self.prefill(self.params, batch)
+        out = self.decode(self.params, cache,
+                          self.step_batch(jnp.argmax(logits, -1)),
+                          jnp.asarray(self.prompt_len, jnp.int32))
+        jax.block_until_ready(out)
+        return time.perf_counter() - t0
+
     def prefill_batch(self, batch) -> float:
         t0 = time.time()
         logits, self.cache = self.prefill(self.params, batch)
@@ -162,14 +193,8 @@ class DecodeServer:
         """One decode step over the held state; returns measured seconds."""
         t0 = time.time()
         pos = jnp.asarray(self.pos, jnp.int32)
-        if self.cfg.frontend == "embeddings":
-            emb = self.params["lm_head"]["w"][:, self.toks].T[:, None, :] \
-                .astype(jnp.dtype(self.cfg.dtype))
-            step_batch = {"frame_embeddings": emb}
-        else:
-            step_batch = {"tokens": self.toks[:, None]}
-        logits, self.cache = self.decode(self.params, self.cache, step_batch,
-                                         pos)
+        logits, self.cache = self.decode(self.params, self.cache,
+                                         self.step_batch(self.toks), pos)
         toks = jnp.argmax(logits, -1)
         toks.block_until_ready()
         self.toks = toks
@@ -178,7 +203,9 @@ class DecodeServer:
         return time.time() - t0
 
 
-def main() -> None:
+def main(argv=None) -> DecodeServer:
+    """Serve one batch from the command line (``argv``, default
+    ``sys.argv``); returns the server, its state after the last step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -214,9 +241,10 @@ def main() -> None:
                          "this many seconds to be worth the re-jit")
     ap.add_argument("--poll-every", type=int, default=4,
                     help="decode steps between store polls in --online mode")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.online and not args.store:
         ap.error("--online requires --store")
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     pcfg = ParallelConfig(flash_threshold=1 << 30, logits_chunk=0)
@@ -288,20 +316,27 @@ def main() -> None:
                 src.refresh()
                 kernel_sources.append(src)
 
+    t0 = time.perf_counter()
     server = DecodeServer(cfg, pcfg, batch=args.batch,
                           prompt_len=args.prompt_len,
                           decode_steps=args.decode_steps, seed=args.seed)
+    jax.block_until_ready(server.params)
+    dt_init = time.perf_counter() - t0
     batch = server.input_batch()
+    dt_warm = server.warmup(batch)
+    print(f"[serve] set-up: init {dt_init:.1f} s, compile + warm-up "
+          f"{dt_warm:.1f} s")
     dt_prefill = server.prefill_batch(batch)
     print(f"[serve] prefill B={args.batch} S={args.prompt_len}: "
-          f"{dt_prefill*1e3:.0f} ms, logits {server.logits_shape}")
+          f"{dt_prefill*1e3:.0f} ms, logits {server.logits_shape}, "
+          f"attention {server.prefill_dispatch}")
 
     if args.online:
         from repro.store.queue import TuningJobQueue
         recorder = ProdRecorder(args.store, args.arch, args.tuned_shape)
         # prefill latency is telemetry, not a decode-step observation: it
-        # includes the prefill jit compile and is in different units than
-        # the tuned step time — journaled configless so it never transfers
+        # is in different units than the tuned step time — journaled
+        # configless so it never transfers
         recorder.record(None, dt_prefill, phase="prefill")
         monitor = DriftMonitor(source.current[1] if source.current else None,
                                factor=args.drift_factor,
@@ -316,7 +351,6 @@ def main() -> None:
                                monitor=monitor, retune_queue=queue,
                                cell_key=source.objective_id,
                                poll_every=args.poll_every,
-                               first_step_warmup=True,
                                kernel_sources=kernel_sources)
         t0 = time.time()
         stats = loop.run(args.decode_steps)
@@ -342,12 +376,17 @@ def main() -> None:
                   f"`python -m repro.launch.retune --store {args.store}`)")
     else:
         t0 = time.time()
+        n_pallas = 0
         for _ in range(args.decode_steps):
             server.decode_step()
+            n_pallas += server.decode_dispatch == "pallas"
         dt = time.time() - t0
         print(f"[serve] decoded {args.decode_steps} steps x B={args.batch}: "
               f"{dt*1e3:.0f} ms ({dt/args.decode_steps*1e3:.1f} ms/step)")
+        print(f"[serve] decode dispatch: {n_pallas} steps Pallas "
+              f"flash-decode, {args.decode_steps - n_pallas} pure-JAX")
     print("[serve] sample tokens:", [int(t[0]) for t in server.out][:12])
+    return server
 
 
 if __name__ == "__main__":

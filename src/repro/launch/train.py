@@ -13,6 +13,7 @@ import argparse
 
 from repro.configs.registry import get_arch, smoke_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.parallel.sharding import ParallelConfig
 from repro.runtime.train import LoopConfig, TrainLoop, run_with_restarts
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--peak-lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,

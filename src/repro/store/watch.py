@@ -543,7 +543,6 @@ class OnlineServeLoop:
                  monitor: Optional[DriftMonitor] = None,
                  retune_queue=None, cell_key: str = "",
                  poll_every: int = 1, clock=time.time,
-                 first_step_warmup: bool = False,
                  kernel_source: Optional[HotConfigSource] = None,
                  kernel_sources: Optional[List[HotConfigSource]] = None):
         self.server = server
@@ -566,10 +565,9 @@ class OnlineServeLoop:
             source.current[0] if source is not None and source.current
             else None)
         self.step = 0          # global decode-step counter across run() calls
-        # first step after a swap pays the re-jit; a real (jit-compiled)
-        # data plane also pays it on its very first step, before any swap —
-        # the launcher passes first_step_warmup=True for that
-        self._warmup = bool(first_step_warmup)
+        # the first step after a swap pays the re-jit (the launcher warms
+        # up the initial step functions before the loop starts)
+        self._warmup = False
 
     def _maybe_swap(self, stats: ServeStats) -> None:
         hit = self.source.refresh() if self.source is not None else None

@@ -154,6 +154,18 @@ def test_process_backend_matches_thread():
     assert all(w.startswith("pid-") for w in res_p.worker_stats)
 
 
+def test_process_backend_refused_on_tpu_host(monkeypatch):
+    """Worker processes would each open the parent's chip: the engine
+    refuses them up front on a TPU backend instead of hanging."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="belongs to one process"):
+        ParallelTuningEngine(_toy_objective(), 8, batch_size=2, workers=2,
+                             backend="process")
+    ParallelTuningEngine(_toy_objective(), 8, batch_size=2, workers=2,
+                         backend="thread")
+
+
 def test_max_in_flight_caps_concurrency():
     class Gauge(Objective):
         def __init__(self, inner):

@@ -122,6 +122,19 @@ def test_vmem_models_monotone():
     assert flash_vmem_bytes(256, 256, 128) < flash_vmem_bytes(1024, 1024, 128)
 
 
+@pytest.mark.parametrize("KV,G,block_kv,admitted", [
+    (8, 2, 1024, True),      # InternLM2-1.8B GQA: compiles on a v5e
+    (16, 1, 512, True),      # MHA at 16 heads: compiles
+    (16, 1, 1024, False),    # the v5e compiler runs out of scoped VMEM
+    (1, 16, 1024, True),     # MQA
+])
+def test_decode_vmem_model_counts_every_kv_head(KV, G, block_kv, admitted):
+    """A decode block holds all KV heads of ``block_kv`` cache slots, double
+    buffered: the model refuses the config the compiler refuses (16 heads
+    × 1024 slots × hd128 bf16 at a 16 MiB scoped-VMEM limit)."""
+    assert ops.decode_valid({"block_kv": block_kv}, KV, G, 128, 2) == admitted
+
+
 # -- GQA-expanded flash dispatch vs the models/layers reference -------------
 
 @pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (4, 1)])   # MHA, GQA, MQA

@@ -64,6 +64,12 @@ class DecodeServer:
     block configs (DESIGN.md §14); derived step-fn bundles are memoized in a
     ``CompiledKernelCache`` keyed by the tunable fields, so swapping BACK to
     a previously-deployed config is a cache hit — no re-jit at all.
+
+    Each step runs inside a profiler span, ``serve.prefill`` or
+    ``serve.decode``, that carries ``batch_id`` and ``pos``; inside it one
+    span per host call (``.inputs``, ``.dispatch``, ``.sample``, ``.sync``)
+    names what the host did while the device waited. Outside a profiler
+    trace each span costs one check.
     """
 
     def __init__(self, cfg, pcfg: ParallelConfig, *, batch: int,
@@ -79,6 +85,8 @@ class DecodeServer:
         self.toks = None
         self.out = []
         self.pos = 0
+        #: ``prefill_batch`` calls so far: the id of the batch in flight
+        self.batch_id = 0
         self.swaps = 0
         self.kernel_swaps = 0
         self.kernel_cache = CompiledKernelCache()
@@ -180,27 +188,48 @@ class DecodeServer:
         return time.perf_counter() - t0
 
     def prefill_batch(self, batch) -> float:
-        t0 = time.time()
-        logits, self.cache = self.prefill(self.params, batch)
-        logits.block_until_ready()
-        self.logits_shape = logits.shape
-        self.toks = jnp.argmax(logits, -1)
-        self.out = [self.toks]
-        self.pos = self.prompt_len
-        return time.time() - t0
+        """Prefill a new batch in place of the held state; returns its
+        seconds on ``time.perf_counter``. Starts batch ``batch_id + 1``:
+        the spans of this batch's steps carry that id."""
+        self.batch_id += 1
+        with jax.profiler.TraceAnnotation("serve.prefill",
+                                          batch_id=self.batch_id, pos=0):
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.prefill.dispatch"):
+                logits, self.cache = self.prefill(self.params, batch)
+            with jax.profiler.TraceAnnotation("serve.prefill.sync"):
+                logits.block_until_ready()
+            with jax.profiler.TraceAnnotation("serve.prefill.sample"):
+                self.toks = jnp.argmax(logits, -1)
+            self.logits_shape = logits.shape
+            self.out = [self.toks]
+            self.pos = self.prompt_len
+            dt = time.perf_counter() - t0
+        return dt
 
     def decode_step(self) -> float:
-        """One decode step over the held state; returns measured seconds."""
-        t0 = time.time()
-        pos = jnp.asarray(self.pos, jnp.int32)
-        logits, self.cache = self.decode(self.params, self.cache,
-                                         self.step_batch(self.toks), pos)
-        toks = jnp.argmax(logits, -1)
-        toks.block_until_ready()
-        self.toks = toks
-        self.out.append(toks)
-        self.pos += 1
-        return time.time() - t0
+        """One decode step over the held state; returns its seconds on
+        ``time.perf_counter``. The step's span carries the batch and the
+        position it writes."""
+        with jax.profiler.TraceAnnotation("serve.decode",
+                                          batch_id=self.batch_id,
+                                          pos=self.pos):
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.decode.inputs"):
+                pos = jnp.asarray(self.pos, jnp.int32)
+                inputs = self.step_batch(self.toks)
+            with jax.profiler.TraceAnnotation("serve.decode.dispatch"):
+                logits, self.cache = self.decode(self.params, self.cache,
+                                                 inputs, pos)
+            with jax.profiler.TraceAnnotation("serve.decode.sample"):
+                toks = jnp.argmax(logits, -1)
+            with jax.profiler.TraceAnnotation("serve.decode.sync"):
+                toks.block_until_ready()
+            self.toks = toks
+            self.out.append(toks)
+            self.pos += 1
+            dt = time.perf_counter() - t0
+        return dt
 
 
 def main(argv=None) -> DecodeServer:
@@ -352,9 +381,9 @@ def main(argv=None) -> DecodeServer:
                                cell_key=source.objective_id,
                                poll_every=args.poll_every,
                                kernel_sources=kernel_sources)
-        t0 = time.time()
+        t0 = time.perf_counter()
         stats = loop.run(args.decode_steps)
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         print(f"[serve] decoded {args.decode_steps} steps x B={args.batch}: "
               f"{dt*1e3:.0f} ms ({dt/args.decode_steps*1e3:.1f} ms/step)")
         for step, cfg_new, value in stats.swaps:
@@ -375,12 +404,12 @@ def main(argv=None) -> DecodeServer:
                   f"re-tune request {tk.id} open (service with "
                   f"`python -m repro.launch.retune --store {args.store}`)")
     else:
-        t0 = time.time()
+        t0 = time.perf_counter()
         n_pallas = 0
         for _ in range(args.decode_steps):
             server.decode_step()
             n_pallas += server.decode_dispatch == "pallas"
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         print(f"[serve] decoded {args.decode_steps} steps x B={args.batch}: "
               f"{dt*1e3:.0f} ms ({dt/args.decode_steps*1e3:.1f} ms/step)")
         print(f"[serve] decode dispatch: {n_pallas} steps Pallas "

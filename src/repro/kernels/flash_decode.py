@@ -37,12 +37,13 @@ from repro.kernels.flash_attention import vmem_tile_bytes
 COMBINE_STRATEGIES = ("jax", "kernel")
 
 
-def _decode_split_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_out_ref,
-                         l_out_ref, m_ref, l_ref, acc_ref, *, steps: int,
-                         kv_heads: int, scale: float):
+def _decode_split_kernel(layer_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
+                         m_out_ref, l_out_ref, m_ref, l_ref, acc_ref, *,
+                         steps: int, kv_heads: int, scale: float):
     """One (batch, split) program: scan this split's KV tiles with online
     softmax for every KV head, emit unnormalized (acc, m, l) partials for
-    the combine.
+    the combine. ``layer_ref`` (scalar prefetch) only steers the K/V
+    index maps to the layer's slab of the stacked cache.
 
     Masked positions carry a -inf bias, so ``exp(s - m_safe)`` is exactly 0
     for them; an all-masked split keeps m = -inf / l = 0 and contributes
@@ -59,8 +60,11 @@ def _decode_split_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_out_ref,
     bias = bias_ref[...]                           # (1, bkv) 0 / -inf
     for h in range(kv_heads):                      # static: one head group
         q = q_ref[h]                               # (G, hd)
-        k = k_ref[:, h, :]                         # (bkv, hd)
-        v = v_ref[:, h, :]
+        if len(k_ref.shape) == 2:                  # MQA: no head axis
+            k, v = k_ref[...], v_ref[...]          # (bkv, hd)
+        else:
+            k = k_ref[:, h, :]                     # (bkv, hd)
+            v = v_ref[:, h, :]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = s + bias
         m_prev = m_ref[h]                          # (G, 1)
@@ -113,21 +117,32 @@ def _decode_combine_kernel(o_ref, m_ref, l_ref, out_ref, *, num_splits: int):
 
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                 bias: jax.Array, *, block_kv: int = 512,
+                 bias: jax.Array, layer: jax.Array, *, block_kv: int = 512,
                  num_splits: int = 1, combine: str = "jax",
                  interpret: bool = False) -> jax.Array:
-    """Single-token cache attention. q (B, H, hd); k/v caches
-    (B, S, KV, hd) with S % (num_splits * block_kv) == 0 (the ops wrapper
-    pads arbitrary capacities); bias (B, S) f32 additive validity mask
-    (0 valid / -inf masked). Returns (B, H, hd) in q's dtype.
+    """Single-token cache attention over one layer of a stacked cache.
+    q (B, H, hd); k/v caches (L, B, S, KV, hd), the caches of L layers in
+    one array, with S % (num_splits * block_kv) == 0 (the ops wrapper pads
+    other capacities); ``layer`` an int32 scalar, the layer to read; bias
+    (B, S) f32 additive validity mask (0 valid / -inf masked). Returns
+    (B, H, hd) in q's dtype.
+
+    ``layer`` is prefetched into SMEM and read by the K/V index maps, so
+    each grid step DMAs its ``block_kv`` slots straight out of the layer's
+    slab: the stack is never sliced or copied, and a decode step that
+    updates it in place hands it to the kernel as it is.
 
     Every block spans whole trailing dims or a tiled slice of the cache
     axis, the only layout the TPU compiler accepts here: a K/V block holds
     all KV heads of ``block_kv`` slots (the kernel loops over heads), q is
-    viewed as (B, KV, G, hd) and the bias as (B, 1, S).
+    viewed as (B, KV, G, hd) and the bias as (B, 1, S). With one KV head
+    the caches are read as (L, B, S, hd): XLA lays a unit head axis out
+    beside the (slot, hd) tiles, not inside them, so only that view reaches
+    the kernel as it lies in memory (with the head axis, XLA would relayout
+    the whole stack for every call).
     """
     B, H, hd = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
+    S, KV = k_cache.shape[2], k_cache.shape[3]
     assert v_cache.shape == k_cache.shape
     assert H % KV == 0, (H, KV)
     assert S % (num_splits * block_kv) == 0, (S, num_splits, block_kv)
@@ -141,34 +156,45 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if not interpret:
         kw["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    spec_q = pl.BlockSpec((None, KV, G, hd), lambda b, s, j: (b, 0, 0, 0))
-    spec_kv = pl.BlockSpec((None, block_kv, KV, hd),
-                           lambda b, s, j: (b, s * steps + j, 0, 0))
+    spec_q = pl.BlockSpec((None, KV, G, hd), lambda b, s, j, l: (b, 0, 0, 0))
+    if KV == 1:
+        k_cache, v_cache = k_cache[:, :, :, 0], v_cache[:, :, :, 0]
+        spec_kv = pl.BlockSpec((None, None, block_kv, hd),
+                               lambda b, s, j, l: (l[0], b, s * steps + j, 0))
+    else:
+        spec_kv = pl.BlockSpec((None, None, block_kv, KV, hd),
+                               lambda b, s, j, l: (l[0], b, s * steps + j,
+                                                   0, 0))
     spec_bias = pl.BlockSpec((None, 1, block_kv),
-                             lambda b, s, j: (b, 0, s * steps + j))
+                             lambda b, s, j, l: (b, 0, s * steps + j))
     spec_o = pl.BlockSpec((None, None, KV, G, hd),
-                          lambda b, s, j: (b, s, 0, 0, 0))
+                          lambda b, s, j, l: (b, s, 0, 0, 0))
     spec_ml = pl.BlockSpec((None, None, KV, G, 1),
-                           lambda b, s, j: (b, s, 0, 0, 0))
+                           lambda b, s, j, l: (b, s, 0, 0, 0))
     ml_shape = jax.ShapeDtypeStruct((B, num_splits, KV, G, 1), jnp.float32)
-    o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_split_kernel, steps=steps, kv_heads=KV,
-                          scale=scale),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[spec_q, spec_kv, spec_kv, spec_bias],
         out_specs=[spec_o, spec_ml, spec_ml],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, num_splits, KV, G, hd), jnp.float32),
-            ml_shape, ml_shape,
-        ],
         scratch_shapes=[
             pltpu.VMEM((KV, G, 1), jnp.float32),       # m
             pltpu.VMEM((KV, G, 1), jnp.float32),       # l
             pltpu.VMEM((KV, G, hd), jnp.float32),      # acc
         ],
+    )
+    o_part, m_part, l_part = pl.pallas_call(
+        functools.partial(_decode_split_kernel, steps=steps, kv_heads=KV,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, num_splits, KV, G, hd), jnp.float32),
+            ml_shape, ml_shape,
+        ],
         interpret=interpret,
         **kw,
-    )(q.reshape(B, KV, G, hd), k_cache, v_cache, bias[:, None, :])
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), q.reshape(B, KV, G, hd),
+      k_cache, v_cache, bias[:, None, :])
 
     if combine == "kernel":
         out = pl.pallas_call(

@@ -95,33 +95,46 @@ def flash_valid(cfg: Dict, hd: int = 128, dtype_bytes: int = 2,
 @functools.partial(jax.jit, static_argnames=("window", "block_kv",
                                              "num_splits", "combine",
                                              "interpret"))
-def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, window=None,
-                     block_kv=512, num_splits=1, combine="jax",
+def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
+                     window=None, block_kv=512, num_splits=1, combine="jax",
                      interpret=None):
     """Split-KV flash decode over the cache, semantics-matched to
     ``models.layers._decode_attention``: q (B, 1, H, hd), caches
     (B, S, KV, hd), ``cache_pos`` (B, S) absolute positions (-1 = empty
-    slot), ``cur_pos`` (B,) the position being decoded. Slot validity —
-    empty, future, or evicted by a rolling ``window`` — becomes an additive
-    f32 bias row (0 / -inf), and caches whose capacity doesn't tile into
-    ``num_splits × block_kv`` are padded with masked slots, so any capacity
-    and occupancy runs. Returns (B, 1, H, hd).
+    slot), ``cur_pos`` (B,) the position being decoded. Returns
+    (B, 1, H, hd).
+
+    With ``layer`` (an int32 scalar, traced or not) the caches are the
+    stacked caches of every layer, (L, B, S, KV, hd), and the kernel reads
+    layer ``layer`` out of them in place; ``cache_pos`` is still that
+    layer's (B, S).
+
+    Slot validity — empty, future, or evicted by a rolling ``window`` —
+    becomes an additive f32 bias row (0 / -inf). A capacity that tiles into
+    ``num_splits × block_kv`` is read as it is; any other is padded with
+    masked slots, which copies the layer's cache on every call (a serving
+    cache is allocated at ``models.layers.decode_capacity`` slots, which
+    the tile divides, so it is never padded).
     """
     if interpret is None:
         interpret = _interpret_default()
-    B, _, H, hd = q.shape
-    S = k_cache.shape[1]
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    S = k_cache.shape[2]
     valid = (cache_pos >= 0) & (cache_pos <= cur_pos[:, None])
     if window is not None:
         valid &= cache_pos > cur_pos[:, None] - window
     bias = jnp.where(valid, 0.0, -jnp.inf).astype(jnp.float32)
-    tile = num_splits * block_kv
-    pad = (-S) % tile
+    pad = (-S) % (num_splits * block_kv)
     if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_cache, v_cache = (jnp.pad(
+            jax.lax.dynamic_index_in_dim(c, layer, keepdims=True),
+            ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            for c in (k_cache, v_cache))
         bias = jnp.pad(bias, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+        layer = 0
     out = _fd.flash_decode(q[:, 0], k_cache, v_cache, bias,
+                           jnp.asarray(layer, jnp.int32),
                            block_kv=block_kv, num_splits=num_splits,
                            combine=combine, interpret=interpret)
     return out[:, None]

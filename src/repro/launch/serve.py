@@ -65,6 +65,13 @@ class DecodeServer:
     ``CompiledKernelCache`` keyed by the tunable fields, so swapping BACK to
     a previously-deployed config is a cache hit — no re-jit at all.
 
+    ``decode`` is the jitted decode step; it leaves the cache it is given
+    intact. ``decode_step`` runs ``decode_in_place``, the same step with the
+    cache donated: the new cache is the old one, updated where the step
+    wrote its token, and the old arrays are deleted. Each cache is passed
+    to it once. A step put in place of ``decode`` (a wrapper, a test
+    double) runs as given, with no donation, since it may keep its input.
+
     Each step runs inside a profiler span, ``serve.prefill`` or
     ``serve.decode``, that carries ``batch_id`` and ``pos``; inside it one
     span per host call (``.inputs``, ``.dispatch``, ``.sample``, ``.sync``)
@@ -114,10 +121,11 @@ class DecodeServer:
             px = ShardCtx(mesh=None, pcfg=self.pcfg)
             prefill = jax.jit(make_prefill_step(self.cfg, px,
                                                 cache_cap=self.cache_cap))
-            decode = jax.jit(make_decode_step(self.cfg, px))
-            return prefill, decode
-        self.prefill, self.decode = self.kernel_cache.get(self._stepfn_key(),
-                                                          build)
+            step = make_decode_step(self.cfg, px)
+            return prefill, jax.jit(step), jax.jit(step, donate_argnums=(1,))
+        self.prefill, self.decode, self.decode_in_place = \
+            self.kernel_cache.get(self._stepfn_key(), build)
+        self._derived_decode = self.decode
 
     def apply_config(self, cfg_dict) -> None:
         self.pcfg = apply_sharding_config(self.pcfg, cfg_dict)
@@ -152,6 +160,12 @@ class DecodeServer:
         return ("pallas" if _pallas_decode_ok(hd, hd, self.pcfg.kernel)
                 else "jax")
 
+    def _decode_fn(self):
+        """The decode step the server runs: ``decode_in_place`` unless
+        ``decode`` was replaced."""
+        return (self.decode_in_place if self.decode is self._derived_decode
+                else self.decode)
+
     def input_batch(self):
         cfg, B = self.cfg, self.batch_size
         if cfg.frontend == "embeddings":
@@ -181,9 +195,9 @@ class DecodeServer:
         that follow run compiled code. Returns seconds."""
         t0 = time.perf_counter()
         logits, cache = self.prefill(self.params, batch)
-        out = self.decode(self.params, cache,
-                          self.step_batch(jnp.argmax(logits, -1)),
-                          jnp.asarray(self.prompt_len, jnp.int32))
+        out = self._decode_fn()(self.params, cache,
+                                self.step_batch(jnp.argmax(logits, -1)),
+                                jnp.asarray(self.prompt_len, jnp.int32))
         jax.block_until_ready(out)
         return time.perf_counter() - t0
 
@@ -196,6 +210,7 @@ class DecodeServer:
                                           batch_id=self.batch_id, pos=0):
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("serve.prefill.dispatch"):
+                self.cache = None           # the held batch's, freed first
                 logits, self.cache = self.prefill(self.params, batch)
             with jax.profiler.TraceAnnotation("serve.prefill.sync"):
                 logits.block_until_ready()
@@ -219,8 +234,9 @@ class DecodeServer:
                 pos = jnp.asarray(self.pos, jnp.int32)
                 inputs = self.step_batch(self.toks)
             with jax.profiler.TraceAnnotation("serve.decode.dispatch"):
-                logits, self.cache = self.decode(self.params, self.cache,
-                                                 inputs, pos)
+                logits, self.cache = self._decode_fn()(self.params,
+                                                       self.cache, inputs,
+                                                       pos)
             with jax.profiler.TraceAnnotation("serve.decode.sample"):
                 toks = jnp.argmax(logits, -1)
             with jax.profiler.TraceAnnotation("serve.decode.sync"):

@@ -51,7 +51,7 @@ def abstract_params_sharded(cfg: ArchConfig, mesh: Optional[Mesh], pcfg: Paralle
 
 def abstract_cache_sharded(cfg: ArchConfig, batch: int, cap: int,
                            mesh: Optional[Mesh], pcfg: ParallelConfig):
-    specs = cache_specs(cfg, batch, cap)
+    specs = cache_specs(cfg, batch, cap, pcfg.kernel)
 
     def mk(spec: ParamSpec):
         dt = jnp.dtype(spec.dtype or cfg.dtype)

@@ -193,13 +193,26 @@ def _pallas_decode_ok(hd: int, hd_v: int, kc) -> bool:
     return kc is not None and kc.use_decode and hd == hd_v
 
 
+def decode_capacity(cap: int, hd: int, kc) -> int:
+    """Slots to allocate for a K/V cache of ``cap`` positions: where the
+    Pallas decode kernel dispatches, ``cap`` rounded up to the kernel's tile
+    (``decode_block_kv × decode_num_splits``), so that it reads the cache
+    as it is and no decode step pads it. The extra slots stay empty
+    (``pos`` -1) and are masked like any empty slot."""
+    if not _pallas_decode_ok(hd, hd, kc):
+        return cap
+    tile = kc.decode_block_kv * kc.decode_num_splits
+    return -(-cap // tile) * tile
+
+
 def _pallas_decode_attention(q, k_cache, v_cache, *, cache_pos, cur_pos,
-                             window, kc):
+                             window, kc, layer):
     """Dispatch one decode step into the tuned split-KV flash-decode kernel
-    (semantics-matched to ``_decode_attention``; parity pinned in tests)."""
+    (semantics-matched to ``_decode_attention``; parity pinned in tests).
+    The caches are stacked over layers; the kernel reads layer ``layer``."""
     from repro.kernels import ops as kernel_ops
     return kernel_ops.decode_attention(
-        q, k_cache, v_cache, cache_pos, cur_pos, window=window,
+        q, k_cache, v_cache, cache_pos, cur_pos, layer, window=window,
         block_kv=kc.decode_block_kv, num_splits=kc.decode_num_splits,
         combine=kc.decode_combine, interpret=kc.interpret)
 
@@ -228,7 +241,12 @@ def _decode_attention(q, k_cache, v_cache, *, cache_pos, cur_pos, window, scale)
 
 
 def gqa_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
-                  cache: Cache, positions, window=None) -> Tuple[jax.Array, Cache]:
+                  cache: Cache, positions, window=None,
+                  layer=None) -> Tuple[jax.Array, Cache]:
+    """In decode, ``cache`` holds the segment's stacked (L, B, S, ...)
+    leaves and ``layer`` is this layer's index into them: the token is
+    written at ``[layer, b, slot]`` and the new stacks are returned
+    (``models.model.forward`` carries them through the layer loop)."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     scale = 1.0 / math.sqrt(hd)
@@ -247,16 +265,18 @@ def gqa_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     new_cache = cache
     if mode == "decode":
         assert cache is not None and S == 1
-        slot = _cache_slot(positions[:, 0], cache["k"].shape[1], window)
-        k_cache = _insert_slot(cache["k"], k, slot)
-        v_cache = _insert_slot(cache["v"], v, slot)
-        cache_pos = _insert_slot(cache["pos"], positions, slot)
+        slot = _cache_slot(positions[:, 0], cache["k"].shape[2], window)
+        k_cache = _insert_slot(cache["k"], k, slot, layer)
+        v_cache = _insert_slot(cache["v"], v, slot, layer)
+        cache_pos = _insert_slot(cache["pos"], positions, slot, layer)
         if _pallas_decode_ok(hd, v.shape[-1], px.pcfg.kernel):
             out = _pallas_decode_attention(
-                q, k_cache, v_cache, cache_pos=cache_pos,
-                cur_pos=positions[:, 0], window=window, kc=px.pcfg.kernel)
+                q, k_cache, v_cache, cache_pos=cache_pos[layer],
+                cur_pos=positions[:, 0], window=window, kc=px.pcfg.kernel,
+                layer=layer)
         else:
-            out = _decode_attention(q, k_cache, v_cache, cache_pos=cache_pos,
+            out = _decode_attention(q, k_cache[layer], v_cache[layer],
+                                    cache_pos=cache_pos[layer],
                                     cur_pos=positions[:, 0], window=window,
                                     scale=scale)
         new_cache = {"k": k_cache, "v": v_cache, "pos": cache_pos}
@@ -305,10 +325,10 @@ def _cache_slot(pos, capacity, window):
     return jnp.remainder(pos, capacity) if window is not None else pos
 
 
-def _insert_slot(buf, val, slot):
-    """Insert val (B,1,...) at per-batch slot (B,) along axis 1."""
-    B = buf.shape[0]
-    return buf.at[jnp.arange(B), slot].set(val[:, 0] if val.ndim == buf.ndim else val[:, 0])
+def _insert_slot(buf, val, slot, layer):
+    """Insert val (B,1,...) at per-batch slot (B,) of layer ``layer`` of a
+    stacked buffer (L,B,S,...): a scatter that updates ``buf`` in place."""
+    return buf.at[layer, jnp.arange(buf.shape[1]), slot].set(val[:, 0])
 
 
 def _prefill_cache(cache, k, v, positions, cap, window):
@@ -336,7 +356,9 @@ def _prefill_cache(cache, k, v, positions, cap, window):
 
 
 def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
-                  cache: Cache, positions) -> Tuple[jax.Array, Cache]:
+                  cache: Cache, positions, layer=None) -> Tuple[jax.Array, Cache]:
+    """In decode, ``cache`` holds stacked (L, B, S, ...) latent caches and
+    the token is written at ``[layer, b, slot]``, as in ``gqa_attention``."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
@@ -357,9 +379,11 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     if mode == "decode":
         assert cache is not None and S == 1
         slot = positions[:, 0]
-        ckv_cache = cache["c_kv"].at[jnp.arange(B), slot].set(c_kv[:, 0])
-        krope_cache = cache["k_rope"].at[jnp.arange(B), slot].set(k_rope[:, 0])
-        pos_cache = cache["pos"].at[jnp.arange(B), slot].set(positions[:, 0])
+        ckv_stack = _insert_slot(cache["c_kv"], c_kv, slot, layer)
+        krope_stack = _insert_slot(cache["k_rope"], k_rope, slot, layer)
+        pos_stack = _insert_slot(cache["pos"], positions, slot, layer)
+        ckv_cache, krope_cache, pos_cache = (
+            ckv_stack[layer], krope_stack[layer], pos_stack[layer])
         # absorbed-weight decode: score/combine in the compressed space
         q_c = jnp.einsum("bshn,lhn->bshl", q_nope, p["wk_nope"])  # (B,1,H,r_kv)
         s = (jnp.einsum("bshl,btl->bhst", q_c, ckv_cache) +
@@ -371,7 +395,7 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
         ctx_c = jnp.einsum("bhst,btl->bshl", prob.astype(ckv_cache.dtype), ckv_cache)
         out = jnp.einsum("bshl,lhv->bshv", ctx_c, p["wv"])  # (B,1,H,dv)
         y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
-        return y, {"c_kv": ckv_cache, "k_rope": krope_cache, "pos": pos_cache}
+        return y, {"c_kv": ckv_stack, "k_rope": krope_stack, "pos": pos_stack}
 
     # train / prefill: expand k_nope & v per head, run flash path
     k_nope = jnp.einsum("bsl,lhn->bshn", c_kv, p["wk_nope"])

@@ -27,7 +27,8 @@ Tree = Dict[str, Any]
 # cache specs
 
 
-def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int) -> Tree:
+def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
+                       kc=None) -> Tree:
     dt = cfg.dtype
     if kind in ("attn", "attn_dense"):
         if cfg.attention == "mla":
@@ -41,8 +42,9 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int) -> Tree
                                  init="neg_ones", dtype="int32"),
             }
         else:
-            c = min(cap, cfg.local_window) if cfg.local_window else cap
             kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+            c = L.decode_capacity(
+                min(cap, cfg.local_window) if cfg.local_window else cap, hd, kc)
             t = {
                 "k": ParamSpec((batch, c, kv, hd),
                                ("act_batch", "act_cache_seq", "act_kv_heads", None),
@@ -97,34 +99,22 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int) -> Tree
     raise ValueError(kind)
 
 
-def cache_specs(cfg: ArchConfig, batch: int, cap: int) -> Tree:
+def cache_specs(cfg: ArchConfig, batch: int, cap: int, kc=None) -> Tree:
+    """Cache leaves for ``cap`` positions, stacked per segment. With the
+    kernel config ``kc`` the K/V caches take ``layers.decode_capacity``
+    slots, the capacity its decode kernel reads with no padding."""
     segs = []
     for (n_rep, cycle) in cfg.pattern_layers():
         cyc: Tree = {}
         for j, kind in enumerate(cycle):
-            layer = _cache_layer_specs(cfg, kind, batch, cap)
+            layer = _cache_layer_specs(cfg, kind, batch, cap, kc)
             cyc[f"{j}:{kind}"] = jax.tree.map(lambda s: _stack_spec(s, n_rep), layer,
                                               is_leaf=is_spec)
         segs.append(cyc)
     return {"segments": segs}
 
 
-def abstract_cache(cfg: ArchConfig, batch: int, cap: int,
-                   shardings: Optional[Tree] = None) -> Tree:
-    specs = cache_specs(cfg, batch, cap)
-
-    def mk(spec: ParamSpec, sh=None):
-        dt = jnp.dtype(spec.dtype or cfg.dtype)
-        if sh is not None:
-            return jax.ShapeDtypeStruct(spec.shape, dt, sharding=sh)
-        return jax.ShapeDtypeStruct(spec.shape, dt)
-
-    if shardings is None:
-        return jax.tree.map(mk, specs, is_leaf=is_spec)
-    return jax.tree.map(mk, specs, shardings, is_leaf=is_spec)
-
-
-def init_cache(cfg: ArchConfig, batch: int, cap: int) -> Tree:
+def init_cache(cfg: ArchConfig, batch: int, cap: int, kc=None) -> Tree:
     def one(spec: ParamSpec):
         dt = jnp.dtype(spec.dtype or cfg.dtype)
         if spec.init == "neg_ones":
@@ -133,7 +123,7 @@ def init_cache(cfg: ArchConfig, batch: int, cap: int) -> Tree:
             return jnp.ones(spec.shape, dt)
         return jnp.zeros(spec.shape, dt)
 
-    return jax.tree.map(one, cache_specs(cfg, batch, cap), is_leaf=is_spec)
+    return jax.tree.map(one, cache_specs(cfg, batch, cap, kc), is_leaf=is_spec)
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +138,7 @@ def _sinusoidal(positions: jax.Array, d: int) -> jax.Array:
 
 
 def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
-                 px: ShardCtx, mode: str, cache, positions, cond):
+                 px: ShardCtx, mode: str, cache, positions, cond, layer=None):
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
     if kind in ("attn", "attn_dense"):
@@ -156,13 +146,15 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
         if cfg.attention == "mla":
             a_cache = {k: cache[k] for k in ("c_kv", "k_rope", "pos")} if cache else None
             a_out, a_cache = L.mla_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
-                                             cache=a_cache, positions=positions)
+                                             cache=a_cache, positions=positions,
+                                             layer=layer)
         else:
             a_cache = {k: cache[k] for k in ("k", "v", "pos")} if cache else None
             a_out, a_cache = L.gqa_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
                                              cache=a_cache, positions=positions,
                                              window=cfg.local_window if kind == "attn"
-                                             and cfg.block_pattern != ("attn",) else None)
+                                             and cfg.block_pattern != ("attn",) else None,
+                                             layer=layer)
         x = x + a_out
         if cache is not None:
             new_cache = dict(cache)
@@ -207,6 +199,27 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
     return x, new_cache, aux
 
 
+#: cache leaves a decode step writes one token into
+_TOKEN_LEAVES = frozenset({"k", "v", "pos", "c_kv", "k_rope"})
+
+
+def _layer_cache(stacks: Tree, i) -> Tree:
+    """Layer ``i``'s decode cache out of its leaves stacked over the
+    segment: the leaves a token is written into are handed whole (the
+    attention layer scatters at ``[i, b, slot]``), every other leaf
+    (recurrent state, cross-attention K/V) is layer ``i``'s slice."""
+    return {n: a if n in _TOKEN_LEAVES
+            else lax.dynamic_index_in_dim(a, i, keepdims=False)
+            for n, a in stacks.items()}
+
+
+def _write_layer_cache(stacks: Tree, new: Tree, i) -> Tree:
+    """``_layer_cache``'s inverse: the stacks with layer ``i``'s update."""
+    return {n: new[n] if n in _TOKEN_LEAVES
+            else lax.dynamic_update_index_in_dim(a, new[n], i, 0)
+            for n, a in stacks.items()}
+
+
 def _remat_wrap(fn, policy: str):
     if policy == "none":
         return fn
@@ -238,20 +251,45 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
         seg_params = params["segments"][si]
         seg_cache = cache["segments"][si] if cache is not None else None
 
-        def cycle_fn(x, cyc_params, cyc_cache):
+        def cycle_fn(x, cyc_params, cyc_cache, layer=None):
+            """With ``layer`` (decode), ``cyc_cache`` is the cycle's cache
+            stacked over the segment, and layer ``layer`` writes into it."""
             aux = jnp.zeros((), jnp.float32)
             new_cc: Tree = {}
             for j, kind in enumerate(cycle):
                 key = f"{j}:{kind}"
                 lc = cyc_cache[key] if cyc_cache is not None else None
+                if layer is not None:
+                    lc = _layer_cache(lc, layer)
                 x, nlc, a = _apply_layer(kind, cyc_params[key], x, cfg=cfg, px=px,
                                          mode=mode, cache=lc, positions=positions,
-                                         cond=cond)
+                                         cond=cond, layer=layer)
+                if layer is not None:
+                    nlc = _write_layer_cache(cyc_cache[key], nlc, layer)
                 new_cc[key] = nlc
                 aux = aux + a
             return x, (new_cc if cyc_cache is not None else None), aux
 
-        if px.pcfg.scan_layers and n_rep > 1:
+        if mode == "decode":
+            # the stacked cache rides the layer loop as carry: an attention
+            # layer scatters its token into it, a recurrent layer writes its
+            # state back at its index, so a donated cache is updated in place
+            if px.pcfg.scan_layers and n_rep > 1:
+                def body(carry, xs):
+                    xx, aux, cc = carry
+                    cp, i = xs
+                    xx, cc, a = cycle_fn(xx, cp, cc, i)
+                    return (xx, aux + a, cc), None
+                (x, aux_total, new_seg_cache), _ = lax.scan(
+                    body, (x, aux_total, seg_cache),
+                    (seg_params, jnp.arange(n_rep)))
+            else:
+                new_seg_cache = seg_cache
+                for i in range(n_rep):
+                    cp = jax.tree.map(lambda a: a[i], seg_params)
+                    x, new_seg_cache, a = cycle_fn(x, cp, new_seg_cache, i)
+                    aux_total = aux_total + a
+        elif px.pcfg.scan_layers and n_rep > 1:
             if seg_cache is not None:
                 def body(carry, xs):
                     xx, aux = carry

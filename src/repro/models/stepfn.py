@@ -131,7 +131,11 @@ def make_train_step(cfg: ArchConfig, px: ShardCtx, optimizer):
 
 
 def make_prefill_step(cfg: ArchConfig, px: ShardCtx, cache_cap: int):
-    """prefill_step(params, batch) -> (last_token_logits, cache)."""
+    """prefill_step(params, batch) -> (last_token_logits, cache).
+
+    The cache holds ``cache_cap`` positions, its K/V at the capacity the
+    decode kernel reads with no padding (``layers.decode_capacity``).
+    """
 
     def prefill_step(params, batch):
         if cfg.frontend == "embeddings":
@@ -143,7 +147,7 @@ def make_prefill_step(cfg: ArchConfig, px: ShardCtx, cache_cap: int):
             embeds = None
             B, S = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-        cache = M.init_cache(cfg, B, cache_cap)
+        cache = M.init_cache(cfg, B, cache_cap, px.pcfg.kernel)
         x, new_cache, _ = M.forward(params, cfg=cfg, px=px, mode="prefill",
                                     tokens=tokens, embeds=embeds,
                                     cond=batch.get("cond"), positions=positions,
@@ -158,7 +162,10 @@ def make_decode_step(cfg: ArchConfig, px: ShardCtx):
     """decode_step(params, cache, batch, pos) -> (logits (B,V), cache).
 
     ``pos`` is the (scalar int32) position of the incoming token; the KV cache
-    holds positions < pos.
+    holds positions < pos. The step writes the token into the cache it is
+    given and returns it: jit it with the cache donated
+    (``donate_argnums=(1,)``) and the update runs in place, with no copy of
+    the cache.
     """
 
     def decode_step(params, cache, batch, pos):

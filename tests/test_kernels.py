@@ -278,14 +278,18 @@ def test_bo_strategy_runs_on_pallas_gp_backend():
 
 # -- flash decode (single-token cache attention, ISSUE 8) --------------------
 
-def _decode_case(B, S, H, KV, hd, cur, *, window=None, rolling=False, seed=0):
+def _decode_case(B, S, H, KV, hd, cur, *, window=None, rolling=False, seed=0,
+                 layers=None):
     """A cache state the way a live server produces it: contiguous fill to
     ``cur`` (later slots empty, ``cache_pos == -1``), or a rolling window's
-    wrapped layout (slot s holds the latest position congruent to s)."""
+    wrapped layout (slot s holds the latest position congruent to s).
+    With ``layers``, K/V are the stacked caches of that many layers, each
+    drawn on its own."""
     rng = np.random.default_rng(seed)
+    stack = (B, S, KV, hd) if layers is None else (layers, B, S, KV, hd)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=stack), jnp.float32)
+    v = jnp.asarray(rng.normal(size=stack), jnp.float32)
     if rolling:
         slots = np.arange(S)
         pos = cur - ((cur - slots) % S)
@@ -297,21 +301,35 @@ def _decode_case(B, S, H, KV, hd, cur, *, window=None, rolling=False, seed=0):
     return q, k, v, cache_pos, cur_pos
 
 
+def _check_decode(q, k, v, cp, cu, *, window, layers, **blocks):
+    """The kernel against ``layers._decode_attention``: on the one layer's
+    cache, or on each of ``layers`` layers read out of the stack by a
+    traced layer index."""
+    from repro.models.layers import _decode_attention
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    for i in ([None] if layers is None else range(layers)):
+        kc, vc = (k, v) if i is None else (k[i], v[i])
+        ref_out = _decode_attention(q, kc, vc, cache_pos=cp, cur_pos=cu,
+                                    window=window, scale=scale)
+        layer = None if i is None else jnp.asarray(i, jnp.int32)
+        out = ops.decode_attention(q, k, v, cp, cu, layer, window=window,
+                                   interpret=True, **blocks)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("layers", [None, 3])
 @pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (4, 1)])
 @pytest.mark.parametrize("num_splits,block_kv,combine",
                          [(1, 64, "jax"), (2, 32, "jax"), (4, 16, "kernel")])
-def test_decode_parity_gqa_and_splits(H, KV, num_splits, block_kv, combine):
+def test_decode_parity_gqa_and_splits(H, KV, num_splits, block_kv, combine,
+                                      layers):
     """Kernel output must match the layers.py pure-JAX decode reference
-    across GQA head ratios and split/combine configurations."""
-    from repro.models.layers import _decode_attention
-    q, k, v, cp, cu = _decode_case(2, 128, H, KV, 16, cur=97)
-    ref_out = _decode_attention(q, k, v, cache_pos=cp, cur_pos=cu,
-                                window=None, scale=1.0 / np.sqrt(16))
-    out = ops.decode_attention(q, k, v, cp, cu, block_kv=block_kv,
-                               num_splits=num_splits, combine=combine,
-                               interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
-                               rtol=2e-4, atol=2e-4)
+    across GQA head ratios and split/combine configurations, on one
+    layer's cache and on each layer of a stacked one."""
+    q, k, v, cp, cu = _decode_case(2, 128, H, KV, 16, cur=97, layers=layers)
+    _check_decode(q, k, v, cp, cu, window=None, layers=layers,
+                  block_kv=block_kv, num_splits=num_splits, combine=combine)
 
 
 @pytest.mark.parametrize("case", [
@@ -320,27 +338,28 @@ def test_decode_parity_gqa_and_splits(H, KV, num_splits, block_kv, combine):
     dict(B=2, S=64, H=4, KV=2, hd=16, cur=150, window=24,
          rolling=True),                                     # rolling window
     dict(B=2, S=96, H=4, KV=1, hd=16, cur=40, window=16),   # window, no wrap
+    dict(B=2, S=128, H=4, KV=2, hd=16, cur=5, layers=2),    # stacked: empty
+    dict(B=1, S=100, H=4, KV=2, hd=16, cur=99, layers=3),   # stacked, ragged
+    dict(B=2, S=64, H=4, KV=2, hd=16, cur=150, window=24,
+         rolling=True, layers=2),                           # stacked, rolling
+    dict(B=2, S=128, H=4, KV=1, hd=16, cur=40, window=16,
+         layers=2),                                         # stacked MQA
 ])
 def test_decode_parity_occupancy_window_capacity(case):
     """Validity-mask edges: partially-empty caches, capacities that don't
     tile into block_kv (padded with masked slots), and windowed/rolling
-    caches — including splits that land entirely in masked territory."""
-    from repro.models.layers import _decode_attention
+    caches — including splits that land entirely in masked territory — on
+    one layer's cache and on each layer of a stacked one."""
     case = dict(case)
     window = case.pop("window", None)
     rolling = case.pop("rolling", False)
-    hd = case["hd"]
     q, k, v, cp, cu = _decode_case(**case, window=window, rolling=rolling)
-    ref_out = _decode_attention(q, k, v, cache_pos=cp, cur_pos=cu,
-                                window=window, scale=1.0 / np.sqrt(hd))
     for num_splits, block_kv, combine in [(1, 64, "jax"), (4, 16, "jax"),
                                           (2, 32, "kernel"),
                                           (8, 32, "kernel")]:
-        out = ops.decode_attention(q, k, v, cp, cu, window=window,
-                                   block_kv=block_kv, num_splits=num_splits,
-                                   combine=combine, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
-                                   rtol=2e-4, atol=2e-4)
+        _check_decode(q, k, v, cp, cu, window=window,
+                      layers=case.get("layers"), block_kv=block_kv,
+                      num_splits=num_splits, combine=combine)
 
 
 def _golden_decode_run(arch, kernel=None):

@@ -13,6 +13,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler's library, and under a
 multi-worker run only the worker given this file should load it.
 """
+import collections
+import re
+
 import pytest
 
 import jax
@@ -118,3 +121,160 @@ def test_matern_gp_compiles(one_chip):
                                               sharding=one_chip)
     _compile(lambda *a: ops.gp_posterior(*a, interpret=False),
              sds(N, d), sds(T, d), sds(T, T), sds(T), sds(T))
+
+
+# -- the decode step updates its cache in place --------------------------
+
+#: decode blocks of the serve cells; layers and batch cut to compile fast
+DECODE_BLOCKS, STEP_L, STEP_B = dict(block_kv=512, num_splits=1), 2, 2
+#: a capacity the decode tile divides, and one it does not (rounded up at
+#: allocation). Large enough that each K/V stack outgrows VMEM, as a
+#: serving cache does: XLA may stage a smaller one through VMEM, a copy a
+#: cache of serving size never gets.
+TILED, RAGGED = 16384, 16384 + 32
+PALLAS = "pallas"
+
+
+def _shape_op(line: str):
+    """(name, shapes of the result, opcode) of one HLO instruction."""
+    m = re.match(r"\s*(?:ROOT )?%(\S+) = ", line)
+    if m is None:
+        return None
+    rest = line[m.end():]
+    if rest.startswith("("):                   # a tuple: balance the parens
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                break
+        result, rest = rest[:i + 1], rest[i + 2:]
+    else:
+        result, _, rest = rest.partition(" ")
+    shapes = {tuple(int(d) for d in s.split(",") if d)
+              for s in re.findall(r"\w+\[([\d,]*)\]", result)}
+    return m.group(1), shapes, rest.partition("(")[0]
+
+
+def _step_arch(attention: str, kv: int):
+    """InternLM2-1.8B (GQA) or DeepSeek-V3 (MLA, its dense layers) at
+    published widths, cut to ``STEP_L`` layers."""
+    import dataclasses
+    from repro.configs.registry import get_arch
+    if attention == "mla":
+        return dataclasses.replace(get_arch("deepseek-v3-671b"),
+                                   num_layers=STEP_L, moe=None)
+    return dataclasses.replace(get_arch("internlm2-1.8b"), num_layers=STEP_L,
+                               num_kv_heads=kv)
+
+
+def _decode_step_hlo(one_chip, arch, kernel, cap, donate=True):
+    from repro.models.params import abstract_params
+    from repro.models.stepfn import make_decode_step, make_prefill_step
+    from repro.parallel.sharding import KernelConfig, ParallelConfig, ShardCtx
+    kc = None if kernel is None else KernelConfig(
+        use_decode=True, decode_block_kv=DECODE_BLOCKS["block_kv"],
+        decode_num_splits=DECODE_BLOCKS["num_splits"], interpret=False)
+    px = ShardCtx(mesh=None, pcfg=ParallelConfig(
+        flash_threshold=1 << 30, logits_chunk=0, kernel=kc))
+    put = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(  # noqa
+        a.shape, a.dtype, sharding=one_chip), tree)
+    params = abstract_params(arch)
+    _, cache = jax.eval_shape(
+        make_prefill_step(arch, px, cache_cap=cap), params,
+        {"tokens": jax.ShapeDtypeStruct((STEP_B, 8), jnp.int32)})
+    step = {"tokens": jax.ShapeDtypeStruct((STEP_B, 1), jnp.int32)}
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    hlo = jax.jit(make_decode_step(arch, px),
+                  donate_argnums=(1,) if donate else ()).lower(
+        put(params), put(cache), put(step), pos).compile().as_text()
+    return hlo, cache
+
+
+def _cache_ops(hlo: str, shapes):
+    """(opcode, shapes) of each instruction outside fused computations whose
+    result has one of ``shapes``, except plumbing and the token's in-place
+    scatters; and the names of those scatters."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    plumbing = {"bitcast", "get-tuple-element", "parameter", "tuple",
+                "while"}
+    comp, ops, writes = None, [], []
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head:
+            comp = head.group(1)
+            continue
+        parsed = _shape_op(line)
+        if comp in fused or parsed is None or not parsed[1] & shapes:
+            continue
+        name, result, op = parsed
+        if op in plumbing:
+            continue
+        if op == "fusion" and re.search(r'op_name="[^"]*/scatter"', line):
+            writes.append(name)
+        else:
+            ops.append((op, tuple(sorted(result & shapes))))
+    return ops, writes
+
+
+@pytest.mark.parametrize("attention,kv,cap,kernel", [
+    ("gqa", KV, TILED, PALLAS), ("gqa", KV, RAGGED, PALLAS),
+    ("gqa", 1, TILED, PALLAS), ("gqa", 1, RAGGED, PALLAS),
+    ("gqa", 4, TILED, PALLAS), ("gqa", KV, TILED, None),
+    ("gqa", 1, TILED, None),
+    # a latent slot is 1/7 of InternLM2's K/V: four times the slots
+    ("mla", None, 4 * TILED, None)])
+def test_donated_decode_step_writes_its_cache_in_place(one_chip, attention,
+                                                       kv, cap, kernel):
+    """Compiled for a v5e with the cache donated, at InternLM2-1.8B's widths
+    (GQA, the Pallas decode on or the pure-JAX one) and DeepSeek-V3's (MLA,
+    pure JAX): every cache leaf is aliased from input to output; outside
+    fused computations no copy, slice, pad or new buffer of a layer's or
+    the stack's K/V shape is made, the token's scatter into the carried
+    stack of each K/V leaf being the only op of that shape. With Pallas the
+    kernel is called once, inside the loop over layers; MQA (KV 1) takes
+    the same path, its caches read without the unit head axis."""
+    arch = _step_arch(attention, kv)
+    hlo, cache = _decode_step_hlo(one_chip, arch, kernel, cap)
+    leaves = jax.tree.leaves(cache)
+    if attention == "gqa":
+        tile = (DECODE_BLOCKS["block_kv"] * DECODE_BLOCKS["num_splits"]
+                if kernel else 1)
+        stack = (STEP_L, STEP_B, -(-cap // tile) * tile, kv, HD)
+        assert {a.shape for a in leaves} == {stack, stack[:3]}
+
+    params = {int(m.group(1)) for m in re.finditer(
+        r"parameter\((\d+)\).*op_name=\"cache\[", hlo)}
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+),",
+                                          alias.group(1) if alias else "")}
+    assert len(params) == len(leaves) and params <= aliased, (params, alias)
+
+    kv_leaves = [a.shape for a in leaves if a.dtype != jnp.int32]
+    shapes = {s for shape in kv_leaves for s in (shape, shape[1:])}
+    shapes |= {tuple(d for d in s if d != 1) for s in shapes}   # MQA views
+    ops, writes = _cache_ops(hlo, shapes)
+    assert len(writes) == len(kv_leaves), writes
+    if attention == "mla":
+        # XLA keeps the 64-wide rope keys with the slot axis minor between
+        # programs and transposes them for the loop: a copy of the k_rope
+        # stack in and out, which the step makes undonated as well (with
+        # a copy of the whole c_kv stack besides)
+        rope = {a.shape for a in leaves
+                if a.shape[-1] == arch.mla.qk_rope_head_dim}
+        assert {s for op, ss in ops for s in ss} <= rope, ops
+        assert all(op == "copy" for op, _ in ops), ops
+        kept, _ = _cache_ops(_decode_step_hlo(one_chip, arch, kernel, cap,
+                                              donate=False)[0], shapes)
+        assert not collections.Counter(ops) - collections.Counter(kept), (
+            ops, kept)
+    else:
+        assert not ops, ops
+
+    kernels = [ln for ln in hlo.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(kernels) == (1 if kernel else 0), kernels
+    if kernel:
+        name = re.match(r"\s*(?:ROOT )?%(\S+) = ", kernels[0]).group(1)
+        body, = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
+        comp = re.search(rf"^%{re.escape(body)} .*?^\}}", hlo, re.M | re.S)
+        assert comp and f"%{name} = " in comp.group(0), (name, body)

@@ -34,6 +34,34 @@ class MoEConfig:
     # "softmax" (classic top-k softmax) or "sigmoid" (DeepSeek-V3 style
     # sigmoid scores with normalized top-k weights).
     router_score: str = "softmax"
+    # group-limited routing (DeepSeek-V3 ``noaux_tc``): experts fall into
+    # ``n_group`` equal groups, a group scores the sum of its two best
+    # experts, and a token picks its experts in its ``topk_group`` best
+    # groups only (1, 1: every expert is a candidate)
+    n_group: int = 1
+    topk_group: int = 1
+    # factor on the normalized routed weights
+    routed_scaling: float = 1.0
+    # experts this chip holds: ``num_experts_held`` of them from
+    # ``first_expert_held`` on, routed over all ``num_experts`` and
+    # computed dropless for the copies that land on them (the chip's share
+    # of an expert-parallel layer); None holds every expert, dispatched by
+    # capacity
+    num_experts_held: Optional[int] = None
+    first_expert_held: int = 0
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2/V3 apply it to
+    the rotary part of their heads."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -85,6 +113,7 @@ class ArchConfig:
     tie_embeddings: bool = False
     scale_embeddings: bool = False    # gemma-style sqrt(d) embed scaling
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRNConfig] = None
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     # Modality frontend: None -> token ids; "embeddings" -> input_specs()

@@ -1,11 +1,13 @@
 """DeepSeek-V3 671B [arXiv:2412.19437; hf].
 
 61L d_model=7168, MLA with 128 heads, MoE: first 3 layers dense (d_ff=18432),
-then 1 shared + 256 routed experts (top-8, d_expert=2048). MTP available as a
+then 1 shared + 256 routed experts (top-8, d_expert=2048), routed by sigmoid
+scores in 8 groups of which a token uses 4 (``noaux_tc``), weights scaled by
+2.5; YaRN rope (factor 40 over 4096 positions). MTP available as a
 config flag (off for dry-runs; see DESIGN.md). The assigned table's d_ff=2048
 is the routed-expert dim; kv=128 reflects MLA's per-head latent heads.
 """
-from repro.configs.arch import ArchConfig, MLAConfig, MoEConfig
+from repro.configs.arch import ArchConfig, MLAConfig, MoEConfig, YaRNConfig
 
 CONFIG = ArchConfig(
     name="deepseek-v3-671b",
@@ -22,9 +24,14 @@ CONFIG = ArchConfig(
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(num_experts=256, top_k=8, d_expert=2048,
                   num_shared_experts=1, capacity_factor=1.25,
-                  router_score="sigmoid"),
+                  router_score="sigmoid", n_group=8, topk_group=4,
+                  routed_scaling=2.5),
     moe_dense_first=3,
     rope_theta=10_000.0,
+    rope_scaling=YaRNConfig(factor=40.0, original_max_position=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                            mscale_all_dim=1.0),
+    norm_eps=1e-6,
     mtp=False,
     notes="MLA latent cache (c_kv=512 + k_rope=64) makes decode_32k cache ~18x "
           "smaller than GQA-equivalent; decode uses absorbed-weight MLA.",

@@ -65,8 +65,14 @@ def smoke_config(name: str) -> ArchConfig:
     if cfg.moe is not None:
         kw["num_layers"] = 3 if cfg.moe_dense_first else 2
         kw["moe_dense_first"] = 1 if cfg.moe_dense_first else 0
+        # grouped routing keeps its groups (4 of 4 experts, 2 picked) and,
+        # as the published model does, drops nothing: every expert held,
+        # computed dropless (a capacity at smoke size drops whole groups)
+        grouped = cfg.moe.n_group > 1
         kw["moe"] = dataclasses.replace(
-            cfg.moe, num_experts=8, top_k=2, d_expert=96,
+            cfg.moe, num_experts=16 if grouped else 8, top_k=2, d_expert=96,
+            n_group=4 if grouped else 1, topk_group=2 if grouped else 1,
+            num_experts_held=16 if grouped else None,
         )
     elif cfg.name.startswith("recurrentgemma"):
         kw["num_layers"] = 5  # (rglru, rglru, attn) + 2 remainder rglru

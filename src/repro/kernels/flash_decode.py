@@ -37,18 +37,22 @@ from repro.kernels.flash_attention import vmem_tile_bytes
 COMBINE_STRATEGIES = ("jax", "kernel")
 
 
-def _decode_split_kernel(layer_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                         m_out_ref, l_out_ref, m_ref, l_ref, acc_ref, *,
-                         steps: int, kv_heads: int, scale: float):
+def _decode_split_kernel(layer_ref, q_ref, k_ref, *refs, steps: int,
+                         kv_heads: int, scale: float, v_width=None):
     """One (batch, split) program: scan this split's KV tiles with online
     softmax for every KV head, emit unnormalized (acc, m, l) partials for
     the combine. ``layer_ref`` (scalar prefetch) only steers the K/V
-    index maps to the layer's slab of the stacked cache.
+    index maps to the layer's slab of the stacked cache. With ``v_width``
+    there is no V operand: V is the first ``v_width`` of each K row (a
+    latent cache), so each tile is read once for both.
 
     Masked positions carry a -inf bias, so ``exp(s - m_safe)`` is exactly 0
     for them; an all-masked split keeps m = -inf / l = 0 and contributes
     nothing downstream.
     """
+    if v_width is None:
+        v_ref, *refs = refs
+    bias_ref, o_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref = refs
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -60,7 +64,10 @@ def _decode_split_kernel(layer_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     bias = bias_ref[...]                           # (1, bkv) 0 / -inf
     for h in range(kv_heads):                      # static: one head group
         q = q_ref[h]                               # (G, hd)
-        if len(k_ref.shape) == 2:                  # MQA: no head axis
+        if v_width is not None:                    # latent: V inside K
+            k = k_ref[...]                         # (bkv, hd)
+            v = k[:, :v_width]
+        elif len(k_ref.shape) == 2:                # MQA: no head axis
             k, v = k_ref[...], v_ref[...]          # (bkv, hd)
         else:
             k = k_ref[:, h, :]                     # (bkv, hd)
@@ -116,16 +123,23 @@ def _decode_combine_kernel(o_ref, m_ref, l_ref, out_ref, *, num_splits: int):
     out_ref[...] = (merged / jnp.maximum(l_tot, 1e-30)).astype(out_ref.dtype)
 
 
-def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                 bias: jax.Array, layer: jax.Array, *, block_kv: int = 512,
+def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
+                 layer: jax.Array, *, block_kv: int = 512,
                  num_splits: int = 1, combine: str = "jax",
-                 interpret: bool = False) -> jax.Array:
+                 interpret: bool = False, v_width=None,
+                 scale=None) -> jax.Array:
     """Single-token cache attention over one layer of a stacked cache.
     q (B, H, hd); k/v caches (L, B, S, KV, hd), the caches of L layers in
     one array, with S % (num_splits * block_kv) == 0 (the ops wrapper pads
     other capacities); ``layer`` an int32 scalar, the layer to read; bias
     (B, S) f32 additive validity mask (0 valid / -inf masked). Returns
-    (B, H, hd) in q's dtype.
+    (B, H, hd) in q's dtype, its scores scaled by ``scale`` (1/sqrt(hd)
+    where None).
+
+    A latent cache (MLA's absorbed decode) comes as ``k_cache`` (L, B, S,
+    hd) with ``v_cache`` None and ``v_width``: one KV head whose K is each
+    slot's whole row and whose V is the row's first ``v_width``; the
+    result is then (B, H, v_width).
 
     ``layer`` is prefetched into SMEM and read by the K/V index maps, so
     each grid step DMAs its ``block_kv`` slots straight out of the layer's
@@ -142,14 +156,22 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     the whole stack for every call).
     """
     B, H, hd = q.shape
-    S, KV = k_cache.shape[2], k_cache.shape[3]
-    assert v_cache.shape == k_cache.shape
+    latent = v_cache is None
+    if latent:
+        assert v_width is not None and v_width <= hd, (hd, v_width)
+        assert k_cache.shape[-1] == hd, (k_cache.shape, hd)
+        S, KV = k_cache.shape[2], 1
+    else:
+        S, KV = k_cache.shape[2], k_cache.shape[3]
+        assert v_cache.shape == k_cache.shape
+    hd_v = hd if v_width is None else v_width
     assert H % KV == 0, (H, KV)
     assert S % (num_splits * block_kv) == 0, (S, num_splits, block_kv)
     assert combine in COMBINE_STRATEGIES, combine
     G = H // KV
     steps = S // (num_splits * block_kv)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     grid = (B, num_splits, steps)
 
     kw = {}
@@ -158,7 +180,8 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     spec_q = pl.BlockSpec((None, KV, G, hd), lambda b, s, j, l: (b, 0, 0, 0))
     if KV == 1:
-        k_cache, v_cache = k_cache[:, :, :, 0], v_cache[:, :, :, 0]
+        if not latent:
+            k_cache, v_cache = k_cache[:, :, :, 0], v_cache[:, :, :, 0]
         spec_kv = pl.BlockSpec((None, None, block_kv, hd),
                                lambda b, s, j, l: (l[0], b, s * steps + j, 0))
     else:
@@ -167,54 +190,56 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                                                    0, 0))
     spec_bias = pl.BlockSpec((None, 1, block_kv),
                              lambda b, s, j, l: (b, 0, s * steps + j))
-    spec_o = pl.BlockSpec((None, None, KV, G, hd),
+    spec_o = pl.BlockSpec((None, None, KV, G, hd_v),
                           lambda b, s, j, l: (b, s, 0, 0, 0))
     spec_ml = pl.BlockSpec((None, None, KV, G, 1),
                            lambda b, s, j, l: (b, s, 0, 0, 0))
     ml_shape = jax.ShapeDtypeStruct((B, num_splits, KV, G, 1), jnp.float32)
+    caches = (k_cache,) if latent else (k_cache, v_cache)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[spec_q, spec_kv, spec_kv, spec_bias],
+        in_specs=[spec_q, *[spec_kv] * len(caches), spec_bias],
         out_specs=[spec_o, spec_ml, spec_ml],
         scratch_shapes=[
             pltpu.VMEM((KV, G, 1), jnp.float32),       # m
             pltpu.VMEM((KV, G, 1), jnp.float32),       # l
-            pltpu.VMEM((KV, G, hd), jnp.float32),      # acc
+            pltpu.VMEM((KV, G, hd_v), jnp.float32),    # acc
         ],
     )
     o_part, m_part, l_part = pl.pallas_call(
         functools.partial(_decode_split_kernel, steps=steps, kv_heads=KV,
-                          scale=scale),
+                          scale=scale, v_width=v_width if latent else None),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, num_splits, KV, G, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, num_splits, KV, G, hd_v), jnp.float32),
             ml_shape, ml_shape,
         ],
         interpret=interpret,
         **kw,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), q.reshape(B, KV, G, hd),
-      k_cache, v_cache, bias[:, None, :])
+      *caches, bias[:, None, :])
 
     if combine == "kernel":
         out = pl.pallas_call(
             functools.partial(_decode_combine_kernel, num_splits=num_splits),
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((None, num_splits, KV, G, hd),
+                pl.BlockSpec((None, num_splits, KV, G, hd_v),
                              lambda b: (b, 0, 0, 0, 0)),
                 pl.BlockSpec((None, num_splits, KV, G, 1),
                              lambda b: (b, 0, 0, 0, 0)),
                 pl.BlockSpec((None, num_splits, KV, G, 1),
                              lambda b: (b, 0, 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, KV, G, hd), lambda b: (b, 0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+            out_specs=pl.BlockSpec((None, KV, G, hd_v),
+                                   lambda b: (b, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, KV, G, hd_v), q.dtype),
             interpret=interpret,
         )(o_part, m_part, l_part)
-        return out.reshape(B, H, hd)
+        return out.reshape(B, H, hd_v)
     merged = _combine_partials_jnp(o_part, m_part, l_part)     # (B,KV,G,hd)
-    return merged.reshape(B, H, hd).astype(q.dtype)
+    return merged.reshape(B, H, hd_v).astype(q.dtype)
 
 
 def decode_vmem_bytes(block_kv: int, KV: int, G: int, hd: int,

@@ -140,6 +140,40 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
     return out[:, None]
 
 
+@functools.partial(jax.jit, static_argnames=("v_width", "scale", "block_kv",
+                                             "num_splits", "combine",
+                                             "interpret"))
+def mla_decode_attention(q, kv_cache, cache_pos, cur_pos, layer=None, *,
+                         v_width, scale, block_kv=512, num_splits=1,
+                         combine="jax", interpret=None):
+    """Split-KV flash decode over a latent cache (MLA's absorbed decode):
+    q (B, 1, H, dk), each head's query in the latent space; the cache
+    (B, S, dk), or with ``layer`` the stacked (L, B, S, dk) caches of every
+    layer, read at ``layer`` in place; K is a slot's whole row and V its
+    first ``v_width``. ``cache_pos`` (B, S) and ``cur_pos`` (B,) mask as in
+    ``decode_attention``, and a capacity the tile does not divide is padded
+    likewise. Returns (B, 1, H, v_width)."""
+    if interpret is None:
+        interpret = _interpret_default()
+    if layer is None:
+        kv_cache, layer = kv_cache[None], 0
+    S = kv_cache.shape[2]
+    valid = (cache_pos >= 0) & (cache_pos <= cur_pos[:, None])
+    bias = jnp.where(valid, 0.0, -jnp.inf).astype(jnp.float32)
+    pad = (-S) % (num_splits * block_kv)
+    if pad:
+        kv_cache = jnp.pad(
+            jax.lax.dynamic_index_in_dim(kv_cache, layer, keepdims=True),
+            ((0, 0), (0, 0), (0, pad), (0, 0)))
+        bias = jnp.pad(bias, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+        layer = 0
+    out = _fd.flash_decode(
+        q[:, 0], kv_cache, None, bias, jnp.asarray(layer, jnp.int32),
+        block_kv=block_kv, num_splits=num_splits, combine=combine,
+        interpret=interpret, v_width=v_width, scale=scale)
+    return out[:, None]
+
+
 def decode_config_space(S: int = 2048) -> SearchSpace:
     """BO target for the decode cell: KV tile length, split count, and the
     cross-split combine strategy. ``S`` is the cache capacity; splits whose

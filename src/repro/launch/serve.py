@@ -143,21 +143,28 @@ class DecodeServer:
     @property
     def prefill_dispatch(self) -> str:
         """``"pallas"`` when prefill attention runs the Pallas flash kernel
-        at this server's prompt length, ``"jax"`` otherwise."""
+        at this server's prompt length, ``"jax"`` otherwise (MLA's
+        differing q and v head dims always take the pure-JAX paths)."""
         from repro.models.layers import _pallas_flash_ok
-        hd = self.cfg.resolved_head_dim
-        return ("pallas" if _pallas_flash_ok(self.prompt_len, hd, hd, None,
+        hd = hd_v = self.cfg.resolved_head_dim
+        m = self.cfg.mla if self.cfg.attention == "mla" else None
+        if m is not None:
+            hd, hd_v = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
+        return ("pallas" if _pallas_flash_ok(self.prompt_len, hd, hd_v, None,
                                              self.pcfg.kernel)
+                and (m is None or self.cfg.rope_scaling is None)
                 else "jax")
 
     @property
     def decode_dispatch(self) -> str:
         """Which implementation the next decode step's attention runs on —
-        ``"pallas"`` when the flash-decode dispatch gate is open, ``"jax"``
-        otherwise. Surfaced per-step by ``ServeStats``."""
+        ``"pallas"`` when the flash-decode dispatch gate is open (for MLA,
+        the latent kernel's), ``"jax"`` otherwise. Surfaced per-step by
+        ``ServeStats``."""
         from repro.models.layers import _pallas_decode_ok
         hd = self.cfg.resolved_head_dim
-        return ("pallas" if _pallas_decode_ok(hd, hd, self.pcfg.kernel)
+        return ("pallas" if _pallas_decode_ok(
+            hd, hd, self.pcfg.kernel, latent=self.cfg.attention == "mla")
                 else "jax")
 
     def _decode_fn(self):

@@ -15,9 +15,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from repro.configs.arch import ArchConfig
+from repro.configs.arch import ArchConfig, YaRNConfig
 from repro.parallel.sharding import ShardCtx, constrain
 
 Cache = Optional[Dict[str, jax.Array]]
@@ -47,12 +48,44 @@ def mlp(p, x: jax.Array, cfg: ArchConfig, px: ShardCtx) -> jax.Array:
 # RoPE
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """positions (B,S) -> cos/sin (B,S,head_dim/2), fp32."""
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                yarn: Optional[YaRNConfig] = None):
+    """positions (B,S) -> cos/sin (B,S,head_dim/2), fp32. With ``yarn`` the
+    frequencies are YaRN's blend of the plain ones and the plain ones over
+    ``factor``, and cos/sin carry its mscale ratio."""
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        m = 1.0
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn), jnp.float32)
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (B,S,half)
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: YaRNConfig) -> np.ndarray:
+    """YaRN's inverse frequencies for a rotary part ``dim`` wide: the plain
+    ones above the fast correction dimension, the plain ones over
+    ``factor`` below the slow one, and a linear ramp between."""
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return extra / yarn.factor * (1 - keep) + extra * keep
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -88,12 +121,20 @@ def _direct_attention(q, k, v, *, q_pos, k_pos, window, scale):
     return out.reshape(B, Sq, H, hd_v)
 
 
+#: bytes of the float32 scores one step of the blockwise scan may hold
+#: (rows × heads × queries × KV block); past it the queries run in chunks
+SCORE_BLOCK_BYTES = 1 << 30
+
+
 def _flash_attention(q, k, v, *, q_pos, k_pos, window, scale, px: ShardCtx):
     """Blockwise online-softmax attention (lax.scan over KV blocks).
 
     Keeps O(Sq·block_kv) transients instead of O(Sq·Sk). With
     ``px.pcfg.attn_q_chunks > 1`` the causal upper-triangle of KV blocks is
     statically skipped per q-chunk (saves ~(1 - (c+1)/2c) of attention FLOPs).
+    A prefill whose scores for one KV block would pass
+    ``SCORE_BLOCK_BYTES`` (DeepSeek-V3's 128 heads over a batch of 2k
+    prompts hold 8.6 GB) doubles its chunks until they fit.
     """
     pcfg = px.pcfg
     B, Sq, H, hd = q.shape
@@ -102,6 +143,9 @@ def _flash_attention(q, k, v, *, q_pos, k_pos, window, scale, px: ShardCtx):
     G = H // KV
     bk = min(pcfg.attn_block_kv, Sk)
     n_chunks = pcfg.attn_q_chunks if (Sq == Sk and Sq % pcfg.attn_q_chunks == 0) else 1
+    while (Sq == Sk and B * H * (Sq // n_chunks) * bk * 4 > SCORE_BLOCK_BYTES
+           and Sq % (2 * n_chunks) == 0):
+        n_chunks *= 2
 
     def run_chunk(qc, qc_pos, k_part, v_part, kp_part):
         nk = k_part.shape[1] // bk
@@ -183,22 +227,24 @@ def _pallas_flash_attention(q, k, v, kc):
         causal=True, interpret=kc.interpret)
 
 
-def _pallas_decode_ok(hd: int, hd_v: int, kc) -> bool:
-    """Static preconditions for the Pallas flash-decode kernel: opted in via
-    KernelConfig and equal k/v head dims (the split kernel accumulates one
-    (G, hd) layout — the MLA ``dn+dr != dv`` variant stays pure-JAX).
+def _pallas_decode_ok(hd: int, hd_v: int, kc, latent: bool = False) -> bool:
+    """Static preconditions for the Pallas flash-decode kernels: opted in via
+    KernelConfig, and equal k/v head dims (the split kernel accumulates one
+    (G, hd) layout) or a latent cache (``latent``: MLA's absorbed decode,
+    whose V is the first ``hd_v`` of each K row, ``ops.mla_decode_attention``).
     Windows, rolling caches, partial occupancy, and capacities that don't
-    tile into the tuned blocks are all handled inside the kernel wrapper
+    tile into the tuned blocks are all handled inside the kernel wrappers
     (validity-bias + padding), so they don't gate dispatch."""
-    return kc is not None and kc.use_decode and hd == hd_v
+    return (kc is not None and kc.use_decode
+            and (latent or hd == hd_v))
 
 
 def decode_capacity(cap: int, hd: int, kc) -> int:
-    """Slots to allocate for a K/V cache of ``cap`` positions: where the
-    Pallas decode kernel dispatches, ``cap`` rounded up to the kernel's tile
-    (``decode_block_kv × decode_num_splits``), so that it reads the cache
-    as it is and no decode step pads it. The extra slots stay empty
-    (``pos`` -1) and are masked like any empty slot."""
+    """Slots to allocate for a K/V (or latent) cache of ``cap`` positions:
+    where a Pallas decode kernel dispatches, ``cap`` rounded up to the
+    kernel's tile (``decode_block_kv × decode_num_splits``), so that it
+    reads the cache as it is and no decode step pads it. The extra slots
+    stay empty (``pos`` -1) and are masked like any empty slot."""
     if not _pallas_decode_ok(hd, hd, kc):
         return cap
     tile = kc.decode_block_kv * kc.decode_num_splits
@@ -355,15 +401,70 @@ def _prefill_cache(cache, k, v, positions, cap, window):
 # MLA (DeepSeek multi-head latent attention)
 
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(q head dim), times YaRN's mscale squared where the config
+    scales its rope over every dimension (``mscale_all_dim``)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+#: lanes of a TPU tile: a latent row is padded to a multiple of them
+LANES = 128
+
+
+def mla_latent_width(m) -> int:
+    """Width of a latent cache slot: c_kv (``kv_lora_rank``) then k_rope
+    (``qk_rope_head_dim``), zero-padded to whole lanes. An unaligned width
+    (576 for DeepSeek-V3) would be laid out by XLA with the slot axis
+    minor, and every decode step would then transpose the whole stack
+    for the kernel."""
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // LANES) * LANES
+
+
+def _pad_lanes(x, width: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _mla_latent_decode(q_lat, lat_stack, pos, cur_pos, *, r, scale, kc,
+                       layer):
+    """Absorbed-weight decode attention over a latent cache: one KV head
+    whose K is each slot's [c_kv ‖ k_rope ‖ 0] row and whose V is its first
+    ``r`` (c_kv). q_lat (B,1,H,W) likewise [q_c ‖ q_rope ‖ 0], ``lat_stack``
+    the stacked (L,B,S,W) cache read at ``layer``; returns the context in
+    the latent space (B,1,H,r)."""
+    if _pallas_decode_ok(0, r, kc, latent=True):
+        from repro.kernels import ops as kernel_ops
+        return kernel_ops.mla_decode_attention(
+            q_lat, lat_stack, pos, cur_pos, layer, v_width=r, scale=scale,
+            block_kv=kc.decode_block_kv, num_splits=kc.decode_num_splits,
+            combine=kc.decode_combine, interpret=kc.interpret)
+    # whole rows on both sides: the zero lanes add nothing, and the cache
+    # is read as it lies, with no slice of it
+    lat = lat_stack[layer]
+    s = jnp.einsum("bshl,btl->bhst", q_lat, lat).astype(jnp.float32) * scale
+    valid = (pos >= 0) & (pos <= cur_pos[:, None])           # (B, cap)
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    prob = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhst,btl->bshl", prob.astype(lat.dtype), lat)
+    return ctx[..., :r]
+
+
 def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                   cache: Cache, positions, layer=None) -> Tuple[jax.Array, Cache]:
-    """In decode, ``cache`` holds stacked (L, B, S, ...) latent caches and
-    the token is written at ``[layer, b, slot]``, as in ``gqa_attention``."""
+    """In decode, ``cache`` holds the stacked (L, B, S, ...) latent cache:
+    ``latent``, each slot's normalized c_kv followed by its roped k_rope
+    (``mla_latent_width``), and ``pos``; the token is written at
+    ``[layer, b, slot]``, as in ``gqa_attention``."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
     dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    scale = 1.0 / math.sqrt(dn + dr)
+    r = m.kv_lora_rank
+    scale = mla_softmax_scale(cfg)
 
     q_lat = rms_norm(x @ p["wq_a"], p["q_a_norm"]["scale"], cfg.norm_eps)
     q = jnp.einsum("bsl,lhk->bshk", q_lat, p["wq_b"])  # (B,S,H,dn+dr)
@@ -371,31 +472,29 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     c_kv = rms_norm(x @ p["wkv_a"], p["kv_a_norm"]["scale"], cfg.norm_eps)  # (B,S,r_kv)
     k_rope = x @ p["wk_rope"]  # (B,S,dr) shared across heads
 
-    cos, sin = rope_tables(positions, dr, cfg.rope_theta)
+    cos, sin = rope_tables(positions, dr, cfg.rope_theta, cfg.rope_scaling)
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
     q_nope = constrain(q_nope, ("act_batch", "act_seq", "act_heads", None), px)
+    width = mla_latent_width(m)
+    latent = _pad_lanes(jnp.concatenate([c_kv, k_rope], axis=-1), width)
 
     if mode == "decode":
         assert cache is not None and S == 1
         slot = positions[:, 0]
-        ckv_stack = _insert_slot(cache["c_kv"], c_kv, slot, layer)
-        krope_stack = _insert_slot(cache["k_rope"], k_rope, slot, layer)
+        lat_stack = _insert_slot(cache["latent"], latent, slot, layer)
         pos_stack = _insert_slot(cache["pos"], positions, slot, layer)
-        ckv_cache, krope_cache, pos_cache = (
-            ckv_stack[layer], krope_stack[layer], pos_stack[layer])
-        # absorbed-weight decode: score/combine in the compressed space
-        q_c = jnp.einsum("bshn,lhn->bshl", q_nope, p["wk_nope"])  # (B,1,H,r_kv)
-        s = (jnp.einsum("bshl,btl->bhst", q_c, ckv_cache) +
-             jnp.einsum("bshr,btr->bhst", q_rope, krope_cache)).astype(jnp.float32)
-        s = s * scale
-        valid = (pos_cache >= 0) & (pos_cache <= positions[:, :1])  # (B, cap)
-        s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
-        prob = jax.nn.softmax(s, axis=-1)
-        ctx_c = jnp.einsum("bhst,btl->bshl", prob.astype(ckv_cache.dtype), ckv_cache)
-        out = jnp.einsum("bshl,lhv->bshv", ctx_c, p["wv"])  # (B,1,H,dv)
+        with jax.named_scope("mla.decode"):
+            # absorbed-weight decode: score/combine in the compressed space
+            q_c = jnp.einsum("bshn,lhn->bshl", q_nope, p["wk_nope"])  # (B,1,H,r)
+            q_lat = _pad_lanes(jnp.concatenate([q_c, q_rope], axis=-1),
+                               width)
+            ctx_c = _mla_latent_decode(
+                q_lat, lat_stack, pos_stack[layer], positions[:, 0], r=r,
+                scale=scale, kc=px.pcfg.kernel, layer=layer)
+            out = jnp.einsum("bshl,lhv->bshv", ctx_c, p["wv"])  # (B,1,H,dv)
         y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
-        return y, {"c_kv": ckv_stack, "k_rope": krope_stack, "pos": pos_stack}
+        return y, {"latent": lat_stack, "pos": pos_stack}
 
     # train / prefill: expand k_nope & v per head, run flash path
     k_nope = jnp.einsum("bsl,lhn->bshn", c_kv, p["wk_nope"])
@@ -403,7 +502,8 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     k_full = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    if _pallas_flash_ok(S, dn + dr, dv, None, px.pcfg.kernel):
+    if (_pallas_flash_ok(S, dn + dr, dv, None, px.pcfg.kernel)
+            and cfg.rope_scaling is None):
         # MLA head dims rarely line up (dn+dr != dv); when they do the
         # tuned kernel applies unchanged — scale is 1/sqrt(q head dim)
         out = _pallas_flash_attention(q_full, k_full, v, px.pcfg.kernel)
@@ -417,11 +517,9 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     new_cache = cache
     if mode == "prefill":
         assert cache is not None
-        cap = cache["c_kv"].shape[1]
-        pad = cap - S
+        pad = cache["latent"].shape[1] - S
         new_cache = {
-            "c_kv": jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
-            "k_rope": jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0))),
+            "latent": jnp.pad(latent, ((0, 0), (0, pad), (0, 0))),
             "pos": jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1),
         }
     return y, new_cache
@@ -429,6 +527,112 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
 
 # ---------------------------------------------------------------------------
 # Mixture of Experts (capacity-based scatter dispatch, EP over `model` axis)
+
+
+def route(p, xg: jax.Array, mo) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router over every expert: xg (G, T, d) -> (scores (G,T,E), the
+    experts picked (G,T,K), their weights (G,T,K)). Sigmoid scores pick by
+    score plus ``router_bias`` and weigh by score alone; with ``n_group`` >
+    1 a token picks only in its ``topk_group`` best groups, a group scoring
+    the sum of its two best biased scores (DeepSeek-V3 ``noaux_tc``). The
+    weights are normalized over the K picked, times ``routed_scaling``."""
+    E, K = mo.num_experts, mo.top_k
+    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    if mo.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        sel = scores + p["router_bias"][None, None, :]
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        sel = scores
+    if mo.n_group > 1:
+        grouped = sel.reshape(*sel.shape[:-1], mo.n_group, E // mo.n_group)
+        group_score = lax.top_k(grouped, 2)[0].sum(-1)        # (G,T,n_group)
+        _, best = lax.top_k(group_score, mo.topk_group)
+        keep = jnp.any(best[..., None] == jnp.arange(mo.n_group), axis=-2)
+        sel = jnp.where(jnp.repeat(keep, E // mo.n_group, axis=-1), sel,
+                        -jnp.inf)
+    _, top_idx = lax.top_k(sel, K)
+    weights = jnp.take_along_axis(scores, top_idx, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-9)
+    if mo.routed_scaling != 1.0:
+        weights = weights * mo.routed_scaling
+    return scores, top_idx, weights
+
+
+#: rows of one tile of the held-expert loop (fewer where the step has fewer
+#: tokens)
+HELD_TILE = 256
+
+
+def moe_held(p, x, *, cfg: ArchConfig, px: ShardCtx, train: bool = False,
+             layer=None) -> Tuple[jax.Array, jax.Array]:
+    """The chip's share of an expert-parallel MoE layer: route every token
+    over all ``num_experts``, compute, dropless, each routed copy that
+    lands on one of the ``num_experts_held`` experts held here, and add the
+    shared experts once. Returns (output, routed copies computed: an int32
+    per batch row).
+
+    Copies are laid out expert by expert, each expert's run padded to whole
+    tiles of ``HELD_TILE`` rows; a loop runs those tiles, gathering a
+    tile's tokens, multiplying them through its expert's weights and
+    adding the weighted rows back. An expert with no copies runs no tile.
+    Only routing tables the size of the token count are allocated, never
+    a buffer of activations per expert. In training (``train``) the loop runs every
+    tile the routing tables have room for, a static count that reverse
+    mode can differentiate; the empty ones add zeros.
+
+    With ``layer`` the expert weights are the segment's stacks
+    (layers, experts, ...) and the loop reads ``[layer, expert]`` out of
+    them in place."""
+    mo = cfg.moe
+    B, S, d = x.shape
+    T, K = B * S, mo.top_k
+    E_l, e0 = mo.num_experts_held, mo.first_expert_held
+    tm = min(HELD_TILE, -(-T // 8) * 8)
+    # a token picks an expert once: at most T copies an expert, T·K in all
+    max_tiles = -(-T * min(K, E_l) // tm) + E_l
+    xt = x.reshape(T, d)
+    with jax.named_scope("moe.route"):
+        _, top_idx, weights = route(p, xt[None], mo)
+        local = top_idx[0].reshape(T * K) - e0
+        held = (local >= 0) & (local < E_l)
+        expert = jnp.where(held, local, E_l)                  # E_l: elsewhere
+        onehot = jax.nn.one_hot(expert, E_l + 1, dtype=jnp.int32)
+        rank = jnp.take_along_axis(jnp.cumsum(onehot, 0) - 1,
+                                   expert[:, None], 1)[:, 0]
+        count = onehot[:, :E_l].sum(0)                        # (E_l,)
+        tiles = -(-count // tm)
+        tile_end = jnp.cumsum(tiles)
+        row = jnp.where(held, (tile_end - tiles)[jnp.minimum(expert, E_l - 1)]
+                        * tm + rank, max_tiles * tm)
+        token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
+        row_token = jnp.full((max_tiles * tm,), T, jnp.int32).at[row].set(
+            token, mode="drop")
+        row_w = jnp.zeros((max_tiles * tm,), jnp.float32).at[row].set(
+            weights[0].reshape(T * K), mode="drop")
+        tile_expert = jnp.minimum(
+            jnp.sum(jnp.arange(max_tiles)[:, None] >= tile_end[None, :], -1),
+            E_l - 1)
+    act = _act(cfg.mlp_act)
+
+    def body(i, y):
+        rows = lax.dynamic_slice_in_dim(row_token, i * tm, tm)
+        w = lax.dynamic_slice_in_dim(row_w, i * tm, tm)
+        e = tile_expert[i] if layer is None else (layer, tile_expert[i])
+        xs = y_in[rows]                                       # (tm, d)
+        h = act(xs @ p["wg"][e]) * (xs @ p["wu"][e])
+        out = (h @ p["wd"][e]) * w[:, None].astype(x.dtype)
+        return y.at[rows].add(out)
+
+    with jax.named_scope("moe.experts"):
+        y_in = jnp.concatenate([xt, jnp.zeros((1, d), x.dtype)])
+        y = lax.fori_loop(0, max_tiles if train else tile_end[-1], body,
+                          jnp.zeros_like(y_in))[:T]
+    if mo.num_shared_experts > 0:
+        y = y + mlp(p["shared"], xt[None], cfg, px)[0]
+    copies = held.reshape(B, S * K).sum(-1, dtype=jnp.int32)
+    return y.reshape(B, S, d), copies
 
 
 def moe_block(p, x, *, cfg: ArchConfig, px: ShardCtx) -> Tuple[jax.Array, jax.Array]:
@@ -449,21 +653,7 @@ def moe_block(p, x, *, cfg: ArchConfig, px: ShardCtx) -> Tuple[jax.Array, jax.Ar
 
     xg = x.reshape(G, Tg, d)
     xg = constrain(xg, ("act_group", None, "act_embed"), px)
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    if mo.router_score == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        sel = scores + p["router_bias"][None, None, :]
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-        sel = scores
-    top_vals, top_idx = lax.top_k(sel, K)  # (G,Tg,K)
-    if mo.router_score == "sigmoid":
-        gate = jnp.take_along_axis(scores, top_idx, axis=-1)
-        weights = gate / (gate.sum(-1, keepdims=True) + 1e-9)
-    else:
-        weights = jnp.take_along_axis(scores, top_idx, axis=-1)
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-9)
+    scores, top_idx, weights = route(p, xg, mo)                  # (G,Tg,K)
 
     # position-in-expert via cumsum of one-hot over flattened (token, k) copies
     flat_e = top_idx.reshape(G, Tg * K)

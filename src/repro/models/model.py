@@ -33,12 +33,12 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
     if kind in ("attn", "attn_dense"):
         if cfg.attention == "mla":
             m = cfg.mla
+            width = L.mla_latent_width(m)
+            c = L.decode_capacity(cap, width, kc)
             t: Tree = {
-                "c_kv": ParamSpec((batch, cap, m.kv_lora_rank),
-                                  ("act_batch", "act_cache_seq", None), init="zeros", dtype=dt),
-                "k_rope": ParamSpec((batch, cap, m.qk_rope_head_dim),
+                "latent": ParamSpec((batch, c, width),
                                     ("act_batch", "act_cache_seq", None), init="zeros", dtype=dt),
-                "pos": ParamSpec((batch, cap), ("act_batch", "act_cache_seq"),
+                "pos": ParamSpec((batch, c), ("act_batch", "act_cache_seq"),
                                  init="neg_ones", dtype="int32"),
             }
         else:
@@ -55,6 +55,11 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
                 "pos": ParamSpec((batch, c), ("act_batch", "act_cache_seq"),
                                  init="neg_ones", dtype="int32"),
             }
+        if kind == "attn" and cfg.moe is not None and cfg.moe.num_experts_held:
+            # routed copies the held experts computed, per row, summed over
+            # decode steps on the device
+            t["routed"] = ParamSpec((batch,), ("act_batch",), init="zeros",
+                                    dtype="int32")
         if cfg.cross_attention:
             kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
             t["cross_k"] = ParamSpec((batch, cfg.cross_seq, kv, hd),
@@ -138,13 +143,14 @@ def _sinusoidal(positions: jax.Array, d: int) -> jax.Array:
 
 
 def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
-                 px: ShardCtx, mode: str, cache, positions, cond, layer=None):
+                 px: ShardCtx, mode: str, cache, positions, cond, layer=None,
+                 expert_layer=None):
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
     if kind in ("attn", "attn_dense"):
         h = L.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if cfg.attention == "mla":
-            a_cache = {k: cache[k] for k in ("c_kv", "k_rope", "pos")} if cache else None
+            a_cache = {k: cache[k] for k in ("latent", "pos")} if cache else None
             a_out, a_cache = L.mla_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
                                              cache=a_cache, positions=positions,
                                              layer=layer)
@@ -169,7 +175,13 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
                     new_cache["cross_k"], new_cache["cross_v"] = ckv
             x = x + L.cross_attention(p["cross"], hc, ckv, cfg=cfg, px=px)
         h2 = L.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        if "moe" in p:
+        if "moe" in p and cfg.moe.num_experts_held:
+            m_out, copies = L.moe_held(p["moe"], h2, cfg=cfg, px=px,
+                                       train=mode == "train",
+                                       layer=expert_layer)
+            if cache is not None and mode == "decode":
+                new_cache["routed"] = cache["routed"] + copies
+        elif "moe" in p:
             m_out, aux = L.moe_block(p["moe"], h2, cfg=cfg, px=px)
         else:
             m_out = L.mlp(p["mlp"], h2, cfg, px)
@@ -200,7 +212,7 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
 
 
 #: cache leaves a decode step writes one token into
-_TOKEN_LEAVES = frozenset({"k", "v", "pos", "c_kv", "k_rope"})
+_TOKEN_LEAVES = frozenset({"k", "v", "pos", "latent"})
 
 
 def _layer_cache(stacks: Tree, i) -> Tree:
@@ -218,6 +230,30 @@ def _write_layer_cache(stacks: Tree, new: Tree, i) -> Tree:
     return {n: new[n] if n in _TOKEN_LEAVES
             else lax.dynamic_update_index_in_dim(a, new[n], i, 0)
             for n, a in stacks.items()}
+
+
+#: a held-expert layer's weights that its expert loop reads at
+#: ``[layer, expert]``
+_HELD_EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def _split_held_experts(cfg: ArchConfig, seg_params: Tree) -> Tuple[Tree, Tree]:
+    """The segment's parameters without the expert stacks of its
+    held-expert layers, and those stacks ({layer key: {name: stack}}). The
+    layer loop slices what it is given layer by layer; the stacks are
+    handed whole, with the layer's index, to ``layers.moe_held``, whose
+    expert loop then reads each expert's weights where they lie. (A layer's
+    slice of them, handed to that loop, would be copied out for it.)"""
+    if cfg.moe is None or not cfg.moe.num_experts_held:
+        return seg_params, {}
+    experts = {k: {n: p["moe"][n] for n in _HELD_EXPERT_LEAVES}
+               for k, p in seg_params.items() if "moe" in p}
+    if not experts:
+        return seg_params, experts
+    rest = {k: (dict(p, moe={n: a for n, a in p["moe"].items()
+                             if n not in _HELD_EXPERT_LEAVES})
+                if k in experts else p) for k, p in seg_params.items()}
+    return rest, experts
 
 
 def _remat_wrap(fn, policy: str):
@@ -248,12 +284,14 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     new_cache_segs = []
     segs = cfg.pattern_layers()
     for si, (n_rep, cycle) in enumerate(segs):
-        seg_params = params["segments"][si]
+        seg_params, experts = _split_held_experts(cfg, params["segments"][si])
         seg_cache = cache["segments"][si] if cache is not None else None
 
-        def cycle_fn(x, cyc_params, cyc_cache, layer=None):
+        def cycle_fn(x, cyc_params, cyc_cache, layer=None, index=None):
             """With ``layer`` (decode), ``cyc_cache`` is the cycle's cache
-            stacked over the segment, and layer ``layer`` writes into it."""
+            stacked over the segment, and layer ``layer`` writes into it.
+            ``index`` is the layer's index in the segment, at which its held
+            experts are read out of their stacks."""
             aux = jnp.zeros((), jnp.float32)
             new_cc: Tree = {}
             for j, kind in enumerate(cycle):
@@ -261,9 +299,13 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                 lc = cyc_cache[key] if cyc_cache is not None else None
                 if layer is not None:
                     lc = _layer_cache(lc, layer)
-                x, nlc, a = _apply_layer(kind, cyc_params[key], x, cfg=cfg, px=px,
+                lp = cyc_params[key]
+                if key in experts:
+                    lp = dict(lp, moe=dict(lp["moe"], **experts[key]))
+                x, nlc, a = _apply_layer(kind, lp, x, cfg=cfg, px=px,
                                          mode=mode, cache=lc, positions=positions,
-                                         cond=cond, layer=layer)
+                                         cond=cond, layer=layer,
+                                         expert_layer=index)
                 if layer is not None:
                     nlc = _write_layer_cache(cyc_cache[key], nlc, layer)
                 new_cc[key] = nlc
@@ -278,7 +320,7 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                 def body(carry, xs):
                     xx, aux, cc = carry
                     cp, i = xs
-                    xx, cc, a = cycle_fn(xx, cp, cc, i)
+                    xx, cc, a = cycle_fn(xx, cp, cc, i, i)
                     return (xx, aux + a, cc), None
                 (x, aux_total, new_seg_cache), _ = lax.scan(
                     body, (x, aux_total, seg_cache),
@@ -287,28 +329,33 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                 new_seg_cache = seg_cache
                 for i in range(n_rep):
                     cp = jax.tree.map(lambda a: a[i], seg_params)
-                    x, new_seg_cache, a = cycle_fn(x, cp, new_seg_cache, i)
+                    x, new_seg_cache, a = cycle_fn(x, cp, new_seg_cache, i, i)
                     aux_total = aux_total + a
         elif px.pcfg.scan_layers and n_rep > 1:
             if seg_cache is not None:
                 def body(carry, xs):
                     xx, aux = carry
-                    cp, cc = xs
+                    cp, cc, i = xs
                     xx, ncc, a = _remat_wrap(
-                        lambda x_, p_, c_: cycle_fn(x_, p_, c_),
-                        px.pcfg.remat if mode == "train" else "none")(xx, cp, cc)
+                        lambda x_, p_, c_, i_: cycle_fn(x_, p_, c_, index=i_),
+                        px.pcfg.remat if mode == "train" else "none")(
+                            xx, cp, cc, i)
                     return (xx, aux + a), ncc
-                (x, aux), new_seg_cache = lax.scan(body, (x, aux_total),
-                                                   (seg_params, seg_cache))
+                (x, aux), new_seg_cache = lax.scan(
+                    body, (x, aux_total),
+                    (seg_params, seg_cache, jnp.arange(n_rep)))
                 aux_total = aux
             else:
-                def body(carry, cp):
+                def body(carry, xs):
                     xx, aux = carry
+                    cp, i = xs
                     xx, _, a = _remat_wrap(
-                        lambda x_, p_: cycle_fn(x_, p_, None),
-                        px.pcfg.remat if mode == "train" else "none")(xx, cp)
+                        lambda x_, p_, i_: cycle_fn(x_, p_, None, index=i_),
+                        px.pcfg.remat if mode == "train" else "none")(
+                            xx, cp, i)
                     return (xx, aux + a), None
-                (x, aux_total), _ = lax.scan(body, (x, aux_total), seg_params)
+                (x, aux_total), _ = lax.scan(body, (x, aux_total),
+                                             (seg_params, jnp.arange(n_rep)))
                 new_seg_cache = None
         else:
             # unrolled: index the stacked leaves layer by layer
@@ -317,7 +364,8 @@ def forward(params: Tree, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                 cp = jax.tree.map(lambda a: a[i], seg_params)
                 cc = (jax.tree.map(lambda a: a[i], seg_cache)
                       if seg_cache is not None else None)
-                fn = _remat_wrap(lambda x_, p_, c_=cc: cycle_fn(x_, p_, c_),
+                fn = _remat_wrap(lambda x_, p_, c_=cc, i_=i: cycle_fn(
+                    x_, p_, c_, index=i_),
                                  px.pcfg.remat if mode == "train" else "none")
                 x, ncc, a = fn(x, cp)
                 aux_total = aux_total + a

@@ -89,11 +89,12 @@ def _mla_specs(cfg: ArchConfig) -> Tree:
 def _moe_specs(cfg: ArchConfig) -> Tree:
     mo = cfg.moe
     d, e, f = cfg.d_model, mo.num_experts, mo.d_expert
+    held = mo.num_experts_held or e      # the router still scores all e
     t: Tree = {
         "router": ParamSpec((d, e), ("embed", None), dtype="float32"),
-        "wg": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
-        "wu": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
-        "wd": ParamSpec((e, f, d), ("experts", "mlp", "embed"),
+        "wg": ParamSpec((held, d, f), ("experts", "embed", "mlp")),
+        "wu": ParamSpec((held, d, f), ("experts", "embed", "mlp")),
+        "wd": ParamSpec((held, f, d), ("experts", "mlp", "embed"),
                         scale=0.02 / math.sqrt(2 * cfg.num_layers)),
     }
     if mo.router_score == "sigmoid":

@@ -72,9 +72,15 @@ def _moe_reference(cfg, p, x):
     else:
         scores = jax.nn.softmax(logits, axis=-1)
         sel = scores
+    if mo.n_group > 1:   # a token's experts lie in its best groups only
+        per = mo.num_experts // mo.n_group
+        group = jax.lax.top_k(sel.reshape(-1, mo.n_group, per), 2)[0].sum(-1)
+        best = jax.lax.top_k(group, mo.topk_group)[1]
+        keep = (best[:, :, None] == jnp.arange(mo.n_group)).any(1)
+        sel = jnp.where(jnp.repeat(keep, per, -1), sel, -jnp.inf)
     top_vals, top_idx = jax.lax.top_k(sel, mo.top_k)
     gate = jnp.take_along_axis(scores, top_idx, axis=-1)
-    w = gate / (gate.sum(-1, keepdims=True) + 1e-9)
+    w = gate / (gate.sum(-1, keepdims=True) + 1e-9) * mo.routed_scaling
     out = jnp.zeros_like(xt)
     act = jax.nn.gelu if cfg.mlp_act == "geglu" else jax.nn.silu
     for e in range(mo.num_experts):

@@ -13,7 +13,6 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler's library, and under a
 multi-worker run only the worker given this file should load it.
 """
-import collections
 import re
 
 import pytest
@@ -108,6 +107,21 @@ def test_flash_decode_largest_valid_config_compiles(one_chip):
         _fd.decode_vmem_bytes(c["block_kv"], KV, H // KV, HD, 2),
         c["num_splits"]))
     _decode(one_chip, KV, cfg["block_kv"], cfg["num_splits"], cfg["combine"])
+
+
+@pytest.mark.parametrize("num_splits,combine", [(1, "jax"), (2, "kernel")])
+def test_latent_decode_compiles(one_chip, num_splits, combine):
+    """DeepSeek-V3's absorbed decode at its serving shape: 128 query rows
+    over one latent KV head of 640 lanes (c_kv 512, k_rope 64, padding), V
+    its first 512, read at a layer index out of a stack of layers."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    B_, H_, W_, L_, S_ = 16, 128, 640, 4, 2560
+    _compile(lambda q, kv, p, c, l: ops.mla_decode_attention(
+        q, kv, p, c, l, v_width=512, scale=0.135, block_kv=512,
+        num_splits=num_splits, combine=combine, interpret=False),
+        sds((B_, 1, H_, W_), BF16), sds((L_, B_, S_, W_), BF16),
+        sds((B_, S_), jnp.int32), sds((B_,), jnp.int32), sds((), jnp.int32))
 
 
 def test_gemm_compiles(one_chip):
@@ -221,26 +235,31 @@ def _cache_ops(hlo: str, shapes):
     ("gqa", 1, TILED, PALLAS), ("gqa", 1, RAGGED, PALLAS),
     ("gqa", 4, TILED, PALLAS), ("gqa", KV, TILED, None),
     ("gqa", 1, TILED, None),
-    # a latent slot is 1/7 of InternLM2's K/V: four times the slots
-    ("mla", None, 4 * TILED, None)])
+    # a latent slot is 1/6 of InternLM2's K/V: four times the slots
+    ("mla", None, 4 * TILED, None), ("mla", None, 4 * TILED, PALLAS),
+    ("mla", None, 4 * TILED + 32, PALLAS)])
 def test_donated_decode_step_writes_its_cache_in_place(one_chip, attention,
                                                        kv, cap, kernel):
     """Compiled for a v5e with the cache donated, at InternLM2-1.8B's widths
     (GQA, the Pallas decode on or the pure-JAX one) and DeepSeek-V3's (MLA,
-    pure JAX): every cache leaf is aliased from input to output; outside
-    fused computations no copy, slice, pad or new buffer of a layer's or
-    the stack's K/V shape is made, the token's scatter into the carried
-    stack of each K/V leaf being the only op of that shape. With Pallas the
-    kernel is called once, inside the loop over layers; MQA (KV 1) takes
-    the same path, its caches read without the unit head axis."""
+    its latent cache read by the latent Pallas kernel or pure JAX): every
+    cache leaf is aliased from input to output; outside fused computations
+    no copy, slice, pad or new buffer of a layer's or the stack's K/V (or
+    latent) shape is made, the token's scatter into the carried stack of
+    each such leaf being the only op of that shape. With Pallas the kernel
+    is called once, inside the loop over layers; MQA (KV 1) takes the same
+    path, its caches read without the unit head axis."""
     arch = _step_arch(attention, kv)
     hlo, cache = _decode_step_hlo(one_chip, arch, kernel, cap)
     leaves = jax.tree.leaves(cache)
+    tile = (DECODE_BLOCKS["block_kv"] * DECODE_BLOCKS["num_splits"]
+            if kernel else 1)
     if attention == "gqa":
-        tile = (DECODE_BLOCKS["block_kv"] * DECODE_BLOCKS["num_splits"]
-                if kernel else 1)
         stack = (STEP_L, STEP_B, -(-cap // tile) * tile, kv, HD)
-        assert {a.shape for a in leaves} == {stack, stack[:3]}
+    else:
+        # c_kv and k_rope in one row of whole lanes (512 + 64 -> 640)
+        stack = (STEP_L, STEP_B, -(-cap // tile) * tile, 640)
+    assert {a.shape for a in leaves} == {stack, stack[:3]}
 
     params = {int(m.group(1)) for m in re.finditer(
         r"parameter\((\d+)\).*op_name=\"cache\[", hlo)}
@@ -254,21 +273,7 @@ def test_donated_decode_step_writes_its_cache_in_place(one_chip, attention,
     shapes |= {tuple(d for d in s if d != 1) for s in shapes}   # MQA views
     ops, writes = _cache_ops(hlo, shapes)
     assert len(writes) == len(kv_leaves), writes
-    if attention == "mla":
-        # XLA keeps the 64-wide rope keys with the slot axis minor between
-        # programs and transposes them for the loop: a copy of the k_rope
-        # stack in and out, which the step makes undonated as well (with
-        # a copy of the whole c_kv stack besides)
-        rope = {a.shape for a in leaves
-                if a.shape[-1] == arch.mla.qk_rope_head_dim}
-        assert {s for op, ss in ops for s in ss} <= rope, ops
-        assert all(op == "copy" for op, _ in ops), ops
-        kept, _ = _cache_ops(_decode_step_hlo(one_chip, arch, kernel, cap,
-                                              donate=False)[0], shapes)
-        assert not collections.Counter(ops) - collections.Counter(kept), (
-            ops, kept)
-    else:
-        assert not ops, ops
+    assert not ops, ops
 
     kernels = [ln for ln in hlo.splitlines()
                if "custom_call_target=\"tpu_custom_call\"" in ln]

@@ -138,7 +138,7 @@ def phase_kernels(*, H: int, KV: int, hd: int, S: int, B: int,
 
     # flash-decode over a cache filled to ``cur`` (later slots empty)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), bf16)
-    kc, vc = (jnp.asarray(rng.normal(size=(B, cache, KV, hd)), bf16)
+    kc, vc = (jnp.asarray(rng.normal(size=(B, KV, cache, hd)), bf16)
               for _ in range(2))
     cur = cache - 7
     pos = np.where(np.arange(cache) <= cur, np.arange(cache), -1)

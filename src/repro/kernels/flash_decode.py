@@ -10,7 +10,9 @@ VMEM, then a cross-split combine merges the per-split partials — the
 tile of every KV head and, per head, that head's G = H/KV grouped query
 rows, so KV tiles are loaded ONCE per group instead of per query head (the
 GQA-expansion the prefill kernel needs would multiply decode HBM traffic by
-G).
+G). The cache is head-major, (L, B, KV, S, hd): each head's tile is a
+contiguous run of ``block_kv`` rows that the loop over heads reads by a
+leading index.
 
 Validity (cache slots never written, slots beyond the current position,
 rolling-window eviction) enters as a precomputed additive f32 bias row
@@ -70,8 +72,7 @@ def _decode_split_kernel(layer_ref, q_ref, k_ref, *refs, steps: int,
         elif len(k_ref.shape) == 2:                # MQA: no head axis
             k, v = k_ref[...], v_ref[...]          # (bkv, hd)
         else:
-            k = k_ref[:, h, :]                     # (bkv, hd)
-            v = v_ref[:, h, :]
+            k, v = k_ref[h], v_ref[h]              # (bkv, hd)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = s + bias
         m_prev = m_ref[h]                          # (G, 1)
@@ -129,9 +130,10 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
                  interpret: bool = False, v_width=None,
                  scale=None) -> jax.Array:
     """Single-token cache attention over one layer of a stacked cache.
-    q (B, H, hd); k/v caches (L, B, S, KV, hd), the caches of L layers in
-    one array, with S % (num_splits * block_kv) == 0 (the ops wrapper pads
-    other capacities); ``layer`` an int32 scalar, the layer to read; bias
+    q (B, H, hd); k/v caches (L, B, KV, S, hd), the head-major caches of L
+    layers in one array, with S % (num_splits * block_kv) == 0 (the ops
+    wrapper pads other capacities); ``layer`` an int32 scalar, the layer to
+    read; bias
     (B, S) f32 additive validity mask (0 valid / -inf masked). Returns
     (B, H, hd) in q's dtype, its scores scaled by ``scale`` (1/sqrt(hd)
     where None).
@@ -146,14 +148,14 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
     slab: the stack is never sliced or copied, and a decode step that
     updates it in place hands it to the kernel as it is.
 
-    Every block spans whole trailing dims or a tiled slice of the cache
-    axis, the only layout the TPU compiler accepts here: a K/V block holds
-    all KV heads of ``block_kv`` slots (the kernel loops over heads), q is
-    viewed as (B, KV, G, hd) and the bias as (B, 1, S). With one KV head
-    the caches are read as (L, B, S, hd): XLA lays a unit head axis out
-    beside the (slot, hd) tiles, not inside them, so only that view reaches
-    the kernel as it lies in memory (with the head axis, XLA would relayout
-    the whole stack for every call).
+    A K/V block holds ``block_kv`` slots of every KV head, (KV, block_kv,
+    hd): each head's part is whole (slot, hd) tiles, one contiguous run of
+    the cache, which the kernel's loop over heads takes by its leading
+    index. All heads share one block so that a grid step moves as much as
+    it can (one head a step would multiply the steps, and their fixed
+    cost, by KV). q is viewed as (B, KV, G, hd) and the bias as (B, 1, S).
+    With one KV head the caches are read as (L, B, S, hd), the unit head
+    axis dropped.
     """
     B, H, hd = q.shape
     latent = v_cache is None
@@ -162,7 +164,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
         assert k_cache.shape[-1] == hd, (k_cache.shape, hd)
         S, KV = k_cache.shape[2], 1
     else:
-        S, KV = k_cache.shape[2], k_cache.shape[3]
+        KV, S = k_cache.shape[2], k_cache.shape[3]
         assert v_cache.shape == k_cache.shape
     hd_v = hd if v_width is None else v_width
     assert H % KV == 0, (H, KV)
@@ -181,13 +183,13 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
     spec_q = pl.BlockSpec((None, KV, G, hd), lambda b, s, j, l: (b, 0, 0, 0))
     if KV == 1:
         if not latent:
-            k_cache, v_cache = k_cache[:, :, :, 0], v_cache[:, :, :, 0]
+            k_cache, v_cache = k_cache[:, :, 0], v_cache[:, :, 0]
         spec_kv = pl.BlockSpec((None, None, block_kv, hd),
                                lambda b, s, j, l: (l[0], b, s * steps + j, 0))
     else:
-        spec_kv = pl.BlockSpec((None, None, block_kv, KV, hd),
-                               lambda b, s, j, l: (l[0], b, s * steps + j,
-                                                   0, 0))
+        spec_kv = pl.BlockSpec((None, None, KV, block_kv, hd),
+                               lambda b, s, j, l: (l[0], b, 0, s * steps + j,
+                                                   0))
     spec_bias = pl.BlockSpec((None, 1, block_kv),
                              lambda b, s, j, l: (b, 0, s * steps + j))
     spec_o = pl.BlockSpec((None, None, KV, G, hd_v),
@@ -244,10 +246,11 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache, bias: jax.Array,
 
 def decode_vmem_bytes(block_kv: int, KV: int, G: int, hd: int,
                       dtype_bytes: int = 2) -> int:
-    """Split-kernel VMEM working set: double-buffered K/V blocks (all KV
-    heads of ``block_kv`` slots), q rows, bias row and partial outputs, the
-    (m, l, acc) scratch, and one head's f32 scores plus probabilities."""
-    kv = 2 * 2 * block_kv * vmem_tile_bytes(KV, hd, dtype_bytes)
+    """Split-kernel VMEM working set: double-buffered K/V blocks (a
+    (block_kv, hd) tile for each of the KV heads), q rows, bias row and
+    partial outputs, the (m, l, acc) scratch, and one head's f32 scores
+    plus probabilities."""
+    kv = 2 * 2 * KV * vmem_tile_bytes(block_kv, hd, dtype_bytes)
     qrows = 2 * KV * vmem_tile_bytes(G, hd, dtype_bytes)
     bias = 2 * vmem_tile_bytes(1, block_kv, 4)
     state = KV * (vmem_tile_bytes(G, hd, 4) + 2 * vmem_tile_bytes(G, 1, 4))

@@ -99,13 +99,13 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
                      window=None, block_kv=512, num_splits=1, combine="jax",
                      interpret=None):
     """Split-KV flash decode over the cache, semantics-matched to
-    ``models.layers._decode_attention``: q (B, 1, H, hd), caches
-    (B, S, KV, hd), ``cache_pos`` (B, S) absolute positions (-1 = empty
+    ``models.layers._decode_attention``: q (B, 1, H, hd), head-major caches
+    (B, KV, S, hd), ``cache_pos`` (B, S) absolute positions (-1 = empty
     slot), ``cur_pos`` (B,) the position being decoded. Returns
     (B, 1, H, hd).
 
     With ``layer`` (an int32 scalar, traced or not) the caches are the
-    stacked caches of every layer, (L, B, S, KV, hd), and the kernel reads
+    stacked caches of every layer, (L, B, KV, S, hd), and the kernel reads
     layer ``layer`` out of them in place; ``cache_pos`` is still that
     layer's (B, S).
 
@@ -120,7 +120,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
         interpret = _interpret_default()
     if layer is None:
         k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
-    S = k_cache.shape[2]
+    S = k_cache.shape[3]
     valid = (cache_pos >= 0) & (cache_pos <= cur_pos[:, None])
     if window is not None:
         valid &= cache_pos > cur_pos[:, None] - window
@@ -129,7 +129,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
     if pad:
         k_cache, v_cache = (jnp.pad(
             jax.lax.dynamic_index_in_dim(c, layer, keepdims=True),
-            ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
             for c in (k_cache, v_cache))
         bias = jnp.pad(bias, ((0, 0), (0, pad)), constant_values=-jnp.inf)
         layer = 0

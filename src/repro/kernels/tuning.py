@@ -139,8 +139,8 @@ def decode_cell(B: int = 4, S: int = 2048, H: int = 8, KV: int = 2,
     cache capacity × heads × KV heads × head dim × batch."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(B, KV, S, hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, KV, S, hd)), dtype)
     cur = max(int(S * fill) - 1, 0)
     pos = np.where(np.arange(S) <= cur, np.arange(S), -1)
     cache_pos = jnp.asarray(np.broadcast_to(pos, (B, S)).copy(), jnp.int32)

@@ -264,21 +264,22 @@ def _pallas_decode_attention(q, k_cache, v_cache, *, cache_pos, cur_pos,
 
 
 def _decode_attention(q, k_cache, v_cache, *, cache_pos, cur_pos, window, scale):
-    """Single-token attention over a cache. q (B,1,H,hd), cache (B,S,KV,hd).
+    """Single-token attention over a cache. q (B,1,H,hd), head-major cache
+    (B,KV,S,hd).
 
     cache_pos (B,S): absolute position stored in each cache slot (-1 = empty).
     """
     B, _, H, hd = q.shape
-    KV = k_cache.shape[2]
+    KV = k_cache.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgh,bskh->bkgs", qg, k_cache).astype(jnp.float32) * scale
+    s = jnp.einsum("bkgh,bksh->bkgs", qg, k_cache).astype(jnp.float32) * scale
     valid = (cache_pos >= 0) & (cache_pos <= cur_pos[:, None])
     if window is not None:
         valid &= cache_pos > cur_pos[:, None] - window
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskh->bkgh", p.astype(v_cache.dtype), v_cache)
+    out = jnp.einsum("bkgs,bksh->bkgh", p.astype(v_cache.dtype), v_cache)
     return out.reshape(B, 1, H, hd)
 
 
@@ -289,10 +290,12 @@ def _decode_attention(q, k_cache, v_cache, *, cache_pos, cur_pos, window, scale)
 def gqa_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                   cache: Cache, positions, window=None,
                   layer=None) -> Tuple[jax.Array, Cache]:
-    """In decode, ``cache`` holds the segment's stacked (L, B, S, ...)
-    leaves and ``layer`` is this layer's index into them: the token is
-    written at ``[layer, b, slot]`` and the new stacks are returned
-    (``models.model.forward`` carries them through the layer loop)."""
+    """In decode, ``cache`` holds the segment's stacked leaves, K/V
+    head-major (L, B, KV, S, hd) and ``pos`` (L, B, S), and ``layer`` is
+    this layer's index into them: the token is written at
+    ``[layer, b, :, slot]`` (``pos`` at ``[layer, b, slot]``) and the new
+    stacks are returned (``models.model.forward`` carries them through the
+    layer loop)."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     scale = 1.0 / math.sqrt(hd)
@@ -311,9 +314,9 @@ def gqa_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     new_cache = cache
     if mode == "decode":
         assert cache is not None and S == 1
-        slot = _cache_slot(positions[:, 0], cache["k"].shape[2], window)
-        k_cache = _insert_slot(cache["k"], k, slot, layer)
-        v_cache = _insert_slot(cache["v"], v, slot, layer)
+        slot = _cache_slot(positions[:, 0], cache["k"].shape[3], window)
+        k_cache = _insert_slot(cache["k"], k, slot, layer, axis=3)
+        v_cache = _insert_slot(cache["v"], v, slot, layer, axis=3)
         cache_pos = _insert_slot(cache["pos"], positions, slot, layer)
         if _pallas_decode_ok(hd, v.shape[-1], px.pcfg.kernel):
             out = _pallas_decode_attention(
@@ -339,7 +342,7 @@ def gqa_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
                                     window=window, scale=scale)
         if mode == "prefill":
             assert cache is not None
-            cap = cache["k"].shape[1]
+            cap = cache["k"].shape[2]
             new_cache = _prefill_cache(cache, k, v, positions, cap, window)
     out = constrain(out, ("act_batch", "act_seq", "act_heads", None), px)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
@@ -371,28 +374,40 @@ def _cache_slot(pos, capacity, window):
     return jnp.remainder(pos, capacity) if window is not None else pos
 
 
-def _insert_slot(buf, val, slot, layer):
+def _insert_slot(buf, val, slot, layer, axis=2):
     """Insert val (B,1,...) at per-batch slot (B,) of layer ``layer`` of a
-    stacked buffer (L,B,S,...): a scatter that updates ``buf`` in place."""
-    return buf.at[layer, jnp.arange(buf.shape[1]), slot].set(val[:, 0])
+    stacked buffer (L,B,...) whose slot axis is ``axis``: a scatter that
+    updates ``buf`` in place. The axes between layer and slot (batch, and
+    a head-major cache's heads) are indexed, not sliced, so the scatter
+    writes rows of the dims after the slot alone; with a slice among them,
+    XLA would lay the stack out slot-major for the scatter and copy it."""
+    n = axis - 1
+    idx = [jnp.arange(buf.shape[d]).reshape((-1,) + (1,) * (n - d))
+           for d in range(1, axis)]
+    return buf.at[(layer, *idx, slot.reshape((-1,) + (1,) * (n - 1)))].set(
+        val[:, 0])
 
 
 def _prefill_cache(cache, k, v, positions, cap, window):
-    """Write prefill K/V into a fresh cache (last `cap` tokens if windowed)."""
+    """Write prefill K/V (B,S,KV,hd) into a fresh head-major cache
+    (B,KV,cap,hd) (last `cap` tokens if windowed). XLA fuses the
+    transpose into the copy into the cache: no pass of its own."""
     B, S = positions.shape
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if S >= cap:
-        kk, vv, pp = k[:, S - cap:], v[:, S - cap:], positions[:, S - cap:]
+        kk, vv = k[:, :, S - cap:], v[:, :, S - cap:]
+        pp = positions[:, S - cap:]
         if window is not None:
             # decode inserts at slot = pos % cap; rearrange so slot s holds the
             # entry whose position ≡ s (mod cap): source j = (s - p0) mod cap.
             idx = (jnp.arange(cap)[None, :] - pp[:, 0:1]) % cap  # (B, cap)
-            kk = jnp.take_along_axis(kk, idx[..., None, None], axis=1)
-            vv = jnp.take_along_axis(vv, idx[..., None, None], axis=1)
+            kk = jnp.take_along_axis(kk, idx[:, None, :, None], axis=2)
+            vv = jnp.take_along_axis(vv, idx[:, None, :, None], axis=2)
             pp = jnp.take_along_axis(pp, idx, axis=1)
         return {"k": kk, "v": vv, "pos": pp}
     pad = cap - S
-    kk = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kk = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    vv = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     pp = jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)
     return {"k": kk, "v": vv, "pos": pp}
 
@@ -458,7 +473,7 @@ def mla_attention(p, x, *, cfg: ArchConfig, px: ShardCtx, mode: str,
     """In decode, ``cache`` holds the stacked (L, B, S, ...) latent cache:
     ``latent``, each slot's normalized c_kv followed by its roped k_rope
     (``mla_latent_width``), and ``pos``; the token is written at
-    ``[layer, b, slot]``, as in ``gqa_attention``."""
+    ``[layer, b, slot]``, as ``pos`` is in ``gqa_attention``."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads

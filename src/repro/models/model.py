@@ -46,11 +46,13 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
             c = L.decode_capacity(
                 min(cap, cfg.local_window) if cfg.local_window else cap, hd, kc)
             t = {
-                "k": ParamSpec((batch, c, kv, hd),
-                               ("act_batch", "act_cache_seq", "act_kv_heads", None),
+                # head-major: each head's slots are one contiguous run,
+                # the tile the decode kernel reads for that head
+                "k": ParamSpec((batch, kv, c, hd),
+                               ("act_batch", "act_kv_heads", "act_cache_seq", None),
                                init="zeros", dtype=dt),
-                "v": ParamSpec((batch, c, kv, hd),
-                               ("act_batch", "act_cache_seq", "act_kv_heads", None),
+                "v": ParamSpec((batch, kv, c, hd),
+                               ("act_batch", "act_kv_heads", "act_cache_seq", None),
                                init="zeros", dtype=dt),
                 "pos": ParamSpec((batch, c), ("act_batch", "act_cache_seq"),
                                  init="neg_ones", dtype="int32"),
@@ -218,7 +220,8 @@ _TOKEN_LEAVES = frozenset({"k", "v", "pos", "latent"})
 def _layer_cache(stacks: Tree, i) -> Tree:
     """Layer ``i``'s decode cache out of its leaves stacked over the
     segment: the leaves a token is written into are handed whole (the
-    attention layer scatters at ``[i, b, slot]``), every other leaf
+    attention layer scatters at ``[i, b, slot]``, head-major K/V at
+    ``[i, b, :, slot]``), every other leaf
     (recurrent state, cross-attention K/V) is layer ``i``'s slice."""
     return {n: a if n in _TOKEN_LEAVES
             else lax.dynamic_index_in_dim(a, i, keepdims=False)

@@ -283,10 +283,10 @@ def _decode_case(B, S, H, KV, hd, cur, *, window=None, rolling=False, seed=0,
     """A cache state the way a live server produces it: contiguous fill to
     ``cur`` (later slots empty, ``cache_pos == -1``), or a rolling window's
     wrapped layout (slot s holds the latest position congruent to s).
-    With ``layers``, K/V are the stacked caches of that many layers, each
-    drawn on its own."""
+    K/V are head-major, (B, KV, S, hd); with ``layers``, the stacked
+    caches of that many layers, each drawn on its own."""
     rng = np.random.default_rng(seed)
-    stack = (B, S, KV, hd) if layers is None else (layers, B, S, KV, hd)
+    stack = (B, KV, S, hd) if layers is None else (layers, B, KV, S, hd)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=stack), jnp.float32)
     v = jnp.asarray(rng.normal(size=stack), jnp.float32)
@@ -360,6 +360,66 @@ def test_decode_parity_occupancy_window_capacity(case):
         _check_decode(q, k, v, cp, cu, window=window,
                       layers=case.get("layers"), block_kv=block_kv,
                       num_splits=num_splits, combine=combine)
+
+
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("KV", [1, 2, 8])
+def test_head_major_cache_write_then_read(KV, window):
+    """A prefill's K/V written into a fresh cache (``_prefill_cache``, the
+    rolling window's wrap included) and one decoded token inserted into a
+    stack of layers (``_insert_slot``) put position p of head h at
+    ``[layer, b, h, slot(p)]``, and nothing into the other layers; the
+    Pallas kernel and ``_decode_attention`` read that stack as attention
+    over the positions the token may see."""
+    from repro.models.layers import (_cache_slot, _decode_attention,
+                                     _insert_slot, _prefill_cache)
+    rng = np.random.default_rng(KV)
+    B, H, hd, S, L, layer = 2, 8, 16, 40, 3, 1
+    cap = 64 if window is None else window
+    k_seq, v_seq = (jnp.asarray(rng.normal(size=(B, S + 1, KV, hd)),
+                                jnp.float32) for _ in range(2))
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    cache = _prefill_cache(None, k_seq[:, :S], v_seq[:, :S], positions,
+                           cap, window)
+    stacks = {n: jnp.zeros((L,) + a.shape, a.dtype).at[layer].set(a)
+              for n, a in cache.items()}
+    tok = jnp.full((B, 1), S, jnp.int32)
+    slot = _cache_slot(tok[:, 0], cap, window)
+    k_st = _insert_slot(stacks["k"], k_seq[:, S:], slot, layer, axis=3)
+    v_st = _insert_slot(stacks["v"], v_seq[:, S:], slot, layer, axis=3)
+    pos_st = _insert_slot(stacks["pos"], tok, slot, layer)
+    assert k_st.shape == (L, B, KV, cap, hd)
+
+    first = 0 if window is None else S + 1 - window
+    want_k = np.zeros((L, B, KV, cap, hd), np.float32)
+    want_pos = np.full((L, B, cap), -1, np.int32)
+    for p in range(first, S + 1):
+        sl = p % cap if window is not None else p
+        want_k[layer, :, :, sl] = np.asarray(k_seq[:, p])
+        want_pos[layer, :, sl] = p
+    np.testing.assert_array_equal(np.asarray(k_st), want_k)
+    np.testing.assert_array_equal(np.asarray(pos_st)[layer],
+                                  want_pos[layer])
+    for other in set(range(L)) - {layer}:
+        assert not np.asarray(k_st[other]).any()
+
+    q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.float32)
+    scale = 1.0 / np.sqrt(hd)
+    kk = np.repeat(np.asarray(k_seq[:, first:]), H // KV, axis=2)
+    vv = np.repeat(np.asarray(v_seq[:, first:]), H // KV, axis=2)
+    sc = np.einsum("bhd,bshd->bhs", np.asarray(q[:, 0]), kk) * scale
+    pr = np.exp(sc - sc.max(-1, keepdims=True))
+    want = np.einsum("bhs,bshd->bhd", pr / pr.sum(-1, keepdims=True), vv)
+    cur = tok[:, 0]
+    ref = _decode_attention(q, k_st[layer], v_st[layer],
+                            cache_pos=pos_st[layer], cur_pos=cur,
+                            window=window, scale=scale)
+    got = ops.decode_attention(q, k_st, v_st, pos_st[layer], cur,
+                               jnp.asarray(layer, jnp.int32), window=window,
+                               block_kv=8, num_splits=2, interpret=True)
+    for out in (ref, got):
+        np.testing.assert_allclose(np.asarray(out)[:, 0], want, rtol=2e-4,
+                                   atol=2e-4)
 
 
 def _golden_decode_run(arch, kernel=None):

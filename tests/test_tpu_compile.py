@@ -66,15 +66,16 @@ def _flash(one_chip, block_q, block_kv):
         qkv, qkv, qkv)
 
 
-def _decode(one_chip, kv, block_kv, num_splits, combine):
+def _decode(one_chip, kv, block_kv, num_splits, combine, batch=B,
+            cache=CACHE):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
                                                  sharding=one_chip)
     _compile(lambda q, k, v, p, c: ops.decode_attention(
         q, k, v, p, c, block_kv=block_kv, num_splits=num_splits,
         combine=combine, interpret=False),
-        sds((B, 1, H, HD), BF16), sds((B, CACHE, kv, HD), BF16),
-        sds((B, CACHE, kv, HD), BF16), sds((B, CACHE), jnp.int32),
-        sds((B,), jnp.int32))
+        sds((batch, 1, H, HD), BF16), sds((batch, kv, cache, HD), BF16),
+        sds((batch, kv, cache, HD), BF16), sds((batch, cache), jnp.int32),
+        sds((batch,), jnp.int32))
 
 
 def test_flash_prefill_compiles(one_chip):
@@ -90,13 +91,16 @@ def test_flash_largest_valid_config_compiles(one_chip):
     _flash(one_chip, cfg["block_q"], cfg["block_kv"])
 
 
-@pytest.mark.parametrize("kv,num_splits,combine", [
-    (KV, 1, "jax"), (KV, 2, "kernel"),   # the serving GQA shape
-    (1, 2, "jax"),                       # MQA
-    (H, 1, "kernel"),                    # MHA
-])
-def test_flash_decode_compiles(one_chip, kv, num_splits, combine):
-    _decode(one_chip, kv, 512, num_splits, combine)
+@pytest.mark.parametrize("kv,num_splits,combine,batch,cache", [
+    (KV, 1, "jax", B, CACHE), (KV, 2, "kernel", B, CACHE),  # smoke shape
+    (1, 2, "jax", B, CACHE),                                # MQA
+    (H, 1, "kernel", B, CACHE),                             # MHA
+    (KV, 1, "jax", 16, 2560),     # internlm2-1.8b.decode's cell
+], ids=["8-1-jax", "8-2-kernel", "1-2-jax", "16-1-kernel",
+        "8-1-jax-B16-S2560"])
+def test_flash_decode_compiles(one_chip, kv, num_splits, combine, batch,
+                               cache):
+    _decode(one_chip, kv, 512, num_splits, combine, batch, cache)
 
 
 def test_flash_decode_largest_valid_config_compiles(one_chip):
@@ -241,7 +245,8 @@ def _cache_ops(hlo: str, shapes):
 def test_donated_decode_step_writes_its_cache_in_place(one_chip, attention,
                                                        kv, cap, kernel):
     """Compiled for a v5e with the cache donated, at InternLM2-1.8B's widths
-    (GQA, the Pallas decode on or the pure-JAX one) and DeepSeek-V3's (MLA,
+    (GQA, its K/V head-major (L, B, KV, S, hd), the Pallas decode on or the
+    pure-JAX one) and DeepSeek-V3's (MLA,
     its latent cache read by the latent Pallas kernel or pure JAX): every
     cache leaf is aliased from input to output; outside fused computations
     no copy, slice, pad or new buffer of a layer's or the stack's K/V (or
@@ -254,12 +259,13 @@ def test_donated_decode_step_writes_its_cache_in_place(one_chip, attention,
     leaves = jax.tree.leaves(cache)
     tile = (DECODE_BLOCKS["block_kv"] * DECODE_BLOCKS["num_splits"]
             if kernel else 1)
+    pos = (STEP_L, STEP_B, -(-cap // tile) * tile)
     if attention == "gqa":
-        stack = (STEP_L, STEP_B, -(-cap // tile) * tile, kv, HD)
+        stack = (STEP_L, STEP_B, kv, pos[2], HD)       # head-major
     else:
         # c_kv and k_rope in one row of whole lanes (512 + 64 -> 640)
-        stack = (STEP_L, STEP_B, -(-cap // tile) * tile, 640)
-    assert {a.shape for a in leaves} == {stack, stack[:3]}
+        stack = pos + (640,)
+    assert {a.shape for a in leaves} == {stack, pos}
 
     params = {int(m.group(1)) for m in re.finditer(
         r"parameter\((\d+)\).*op_name=\"cache\[", hlo)}

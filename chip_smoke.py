@@ -121,7 +121,7 @@ def phase_kernels(*, H: int, KV: int, hd: int, S: int, B: int,
                   cache: int) -> None:
     from repro.core.gp_fast import IncrementalGP
     from repro.kernels import ops, ref
-    from repro.models.layers import _decode_attention
+    from repro.models.attention import _decode_attention
     bf16 = jnp.bfloat16
     tol = KERNEL_TOL["bfloat16"]
     rng = np.random.default_rng(0)

@@ -2,7 +2,7 @@
 
 Online-softmax over KV blocks with the running (m, l, acc) state in VMEM —
 the accumulator NEVER touches HBM, which is precisely what the pure-JAX
-blockwise attention in repro.models.layers cannot express (its fp32
+blockwise attention in repro.models.attention cannot express (its fp32
 accumulator is an HLO tensor; see EXPERIMENTS.md §Perf hillclimb #3).
 Grid: (batch, heads, q_blocks, kv_blocks), kv innermost/arbitrary.
 

@@ -99,7 +99,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
                      window=None, block_kv=512, num_splits=1, combine="jax",
                      interpret=None):
     """Split-KV flash decode over the cache, semantics-matched to
-    ``models.layers._decode_attention``: q (B, 1, H, hd), head-major caches
+    ``models.attention._decode_attention``: q (B, 1, H, hd), head-major caches
     (B, KV, S, hd), ``cache_pos`` (B, S) absolute positions (-1 = empty
     slot), ``cur_pos`` (B,) the position being decoded. Returns
     (B, 1, H, hd).
@@ -113,7 +113,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, cur_pos, layer=None,
     becomes an additive f32 bias row (0 / -inf). A capacity that tiles into
     ``num_splits × block_kv`` is read as it is; any other is padded with
     masked slots, which copies the layer's cache on every call (a serving
-    cache is allocated at ``models.layers.decode_capacity`` slots, which
+    cache is allocated at ``models.attention.decode_capacity`` slots, which
     the tile divides, so it is never padded).
     """
     if interpret is None:

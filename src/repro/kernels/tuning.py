@@ -37,6 +37,8 @@ from repro.core.objectives import Objective
 from repro.core.searchspace import SearchSpace
 from repro.kernels import ops
 from repro.launch.roofline import VMEM_BYTES
+from repro.parallel.sharding import ParallelConfig
+from repro.store.resolve import apply_kernel_config
 
 KERNEL_NAMES = ("gemm", "flash", "decode", "gp")
 
@@ -346,17 +348,15 @@ def kernel_config_from_store(store, *, S: int, hd: int,
     """Resolve a ``KernelConfig`` for a serving cell's prefill problem
     (sequence length ``S``, head dim ``hd``) from stored flash-cell tunings.
     None when the store has no usable record (caller keeps pure-JAX)."""
-    from repro.parallel.sharding import KernelConfig
     hit = best_kernel_config(store, "flash", None, device)
     if hit is None:
         return None
-    cfg = hit[0]
-    bq, bkv = int(cfg["block_q"]), int(cfg["block_kv"])
-    if S % bq != 0 or S % bkv != 0:
+    blocks = {k: int(hit[0][k]) for k in ("block_q", "block_kv")}
+    if any(S % b for b in blocks.values()):
         return None             # tuned blocks don't tile this server's S
-    if not ops.flash_valid({"block_q": bq, "block_kv": bkv}, hd):
+    if not ops.flash_valid(blocks, hd):
         return None
-    return KernelConfig(use_flash=True, flash_block_q=bq, flash_block_kv=bkv)
+    return apply_kernel_config(ParallelConfig(), blocks).kernel
 
 
 def decode_kernel_config_from_store(store, *, cache_cap: int, H: int, KV: int,
@@ -367,7 +367,6 @@ def decode_kernel_config_from_store(store, *, cache_cap: int, H: int, KV: int,
     both tuned flash AND tuned decode blocks in one ``KernelConfig``).
     None when no stored record is usable for this cache (caller keeps the
     pure-JAX decode path)."""
-    from repro.parallel.sharding import KernelConfig
     hit = best_kernel_config(store, "decode", None, device)
     if hit is None:
         return None
@@ -378,7 +377,4 @@ def decode_kernel_config_from_store(store, *, cache_cap: int, H: int, KV: int,
     G = H // max(KV, 1)
     if not ops.decode_valid({"block_kv": bkv}, KV, G, hd):
         return None
-    base = base if base is not None else KernelConfig()
-    return base.replace(use_decode=True, decode_block_kv=bkv,
-                        decode_num_splits=ns,
-                        decode_combine=str(cfg["combine"]))
+    return apply_kernel_config(ParallelConfig(kernel=base), cfg).kernel

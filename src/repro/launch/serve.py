@@ -22,14 +22,17 @@ improvements smaller than the re-jit cost are not worth a swap.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_arch, smoke_config
-from repro.kernels.cache import CompiledKernelCache, config_key
+from repro.kernels.cache import CompiledKernelCache
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models.attention import (ATTENTION_KINDS, decode_path,
+                                    layer_window, prefill_path)
 from repro.models.params import init_params
 from repro.models.stepfn import make_decode_step, make_prefill_step
 from repro.parallel.sharding import ParallelConfig, ShardCtx
@@ -99,22 +102,14 @@ class DecodeServer:
         self.kernel_cache = CompiledKernelCache()
         self._derive()
 
-    def _stepfn_key(self):
-        """Hashable identity of the derived step functions: every tunable
-        ParallelConfig field a store record can overlay, plus the kernel
-        block config. Rule tables are excluded — serving never hot-swaps
-        them (they change the mesh, which IS a restart)."""
-        p = self.pcfg
-        kc = p.kernel
-        kernel = (() if kc is None else
-                  ("flash", kc.use_flash, kc.flash_block_q,
-                   kc.flash_block_kv, "decode", kc.use_decode,
-                   kc.decode_block_kv, kc.decode_num_splits,
-                   kc.decode_combine, kc.interpret))
-        return (p.remat, p.microbatches, p.attn_block_q, p.attn_block_kv,
-                p.attn_q_chunks, p.capacity_factor, p.logits_chunk,
-                p.opt_moment_dtype, p.scan_layers, p.flash_threshold,
-                p.mlstm_chunk, p.mlstm_bf16_streams, p.moe_combine, kernel)
+    @staticmethod
+    def _stepfn_key(pcfg: ParallelConfig):
+        """Hashable identity of the step functions derived from ``pcfg``:
+        every field but the rule tables, the kernel config whole. Rule
+        tables are excluded — serving never hot-swaps them (they change
+        the mesh, which IS a restart)."""
+        return tuple(getattr(pcfg, f.name) for f in dataclasses.fields(pcfg)
+                     if f.name not in ("param_rules", "act_rules"))
 
     def _derive(self) -> None:
         def build():
@@ -124,7 +119,7 @@ class DecodeServer:
             step = make_decode_step(self.cfg, px)
             return prefill, jax.jit(step), jax.jit(step, donate_argnums=(1,))
         self.prefill, self.decode, self.decode_in_place = \
-            self.kernel_cache.get(self._stepfn_key(), build)
+            self.kernel_cache.get(self._stepfn_key(self.pcfg), build)
         self._derived_decode = self.decode
 
     def apply_config(self, cfg_dict) -> None:
@@ -142,30 +137,20 @@ class DecodeServer:
 
     @property
     def prefill_dispatch(self) -> str:
-        """``"pallas"`` when prefill attention runs the Pallas flash kernel
-        at this server's prompt length, ``"jax"`` otherwise (MLA's
-        differing q and v head dims always take the pure-JAX paths)."""
-        from repro.models.layers import _pallas_flash_ok
-        hd = hd_v = self.cfg.resolved_head_dim
-        m = self.cfg.mla if self.cfg.attention == "mla" else None
-        if m is not None:
-            hd, hd_v = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
-        return ("pallas" if _pallas_flash_ok(self.prompt_len, hd, hd_v, None,
-                                             self.pcfg.kernel)
-                and (m is None or self.cfg.rope_scaling is None)
-                else "jax")
+        """``"pallas"`` when every attention layer's prefill runs the Pallas
+        flash kernel at this server's prompt length, ``"jax"`` otherwise."""
+        paths = {prefill_path(self.cfg, self.pcfg, self.prompt_len,
+                              layer_window(self.cfg, kind))
+                 for _, cycle in self.cfg.pattern_layers() for kind in cycle
+                 if kind in ATTENTION_KINDS}
+        return "pallas" if paths == {"pallas"} else "jax"
 
     @property
     def decode_dispatch(self) -> str:
-        """Which implementation the next decode step's attention runs on —
-        ``"pallas"`` when the flash-decode dispatch gate is open (for MLA,
-        the latent kernel's), ``"jax"`` otherwise. Surfaced per-step by
+        """Which implementation the next decode step's attention runs on
+        (``decode_path``): ``"pallas"`` or ``"jax"``. Surfaced per-step by
         ``ServeStats``."""
-        from repro.models.layers import _pallas_decode_ok
-        hd = self.cfg.resolved_head_dim
-        return ("pallas" if _pallas_decode_ok(
-            hd, hd, self.pcfg.kernel, latent=self.cfg.attention == "mla")
-                else "jax")
+        return decode_path(self.cfg, self.pcfg)
 
     def _decode_fn(self):
         """The decode step the server runs: ``decode_in_place`` unless

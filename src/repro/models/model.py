@@ -7,7 +7,6 @@ O(depth) — essential for 61-layer MoE models lowered against 512 devices.
 """
 from __future__ import annotations
 
-import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -16,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.arch import ArchConfig
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.params import ParamSpec, is_spec, _stack_spec
 from repro.parallel.sharding import ShardCtx, constrain
@@ -30,46 +30,13 @@ Tree = Dict[str, Any]
 def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
                        kc=None) -> Tree:
     dt = cfg.dtype
-    if kind in ("attn", "attn_dense"):
-        if cfg.attention == "mla":
-            m = cfg.mla
-            width = L.mla_latent_width(m)
-            c = L.decode_capacity(cap, width, kc)
-            t: Tree = {
-                "latent": ParamSpec((batch, c, width),
-                                    ("act_batch", "act_cache_seq", None), init="zeros", dtype=dt),
-                "pos": ParamSpec((batch, c), ("act_batch", "act_cache_seq"),
-                                 init="neg_ones", dtype="int32"),
-            }
-        else:
-            kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-            c = L.decode_capacity(
-                min(cap, cfg.local_window) if cfg.local_window else cap, hd, kc)
-            t = {
-                # head-major: each head's slots are one contiguous run,
-                # the tile the decode kernel reads for that head
-                "k": ParamSpec((batch, kv, c, hd),
-                               ("act_batch", "act_kv_heads", "act_cache_seq", None),
-                               init="zeros", dtype=dt),
-                "v": ParamSpec((batch, kv, c, hd),
-                               ("act_batch", "act_kv_heads", "act_cache_seq", None),
-                               init="zeros", dtype=dt),
-                "pos": ParamSpec((batch, c), ("act_batch", "act_cache_seq"),
-                                 init="neg_ones", dtype="int32"),
-            }
+    if kind in A.ATTENTION_KINDS:
+        t = A.cache_layer_specs(cfg, kind, batch, cap, kc)
         if kind == "attn" and cfg.moe is not None and cfg.moe.num_experts_held:
             # routed copies the held experts computed, per row, summed over
             # decode steps on the device
             t["routed"] = ParamSpec((batch,), ("act_batch",), init="zeros",
                                     dtype="int32")
-        if cfg.cross_attention:
-            kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-            t["cross_k"] = ParamSpec((batch, cfg.cross_seq, kv, hd),
-                                     ("act_batch", None, "act_kv_heads", None),
-                                     init="zeros", dtype=dt)
-            t["cross_v"] = ParamSpec((batch, cfg.cross_seq, kv, hd),
-                                     ("act_batch", None, "act_kv_heads", None),
-                                     init="zeros", dtype=dt)
         return t
     if kind == "rglru":
         r = cfg.rglru
@@ -108,7 +75,7 @@ def _cache_layer_specs(cfg: ArchConfig, kind: str, batch: int, cap: int,
 
 def cache_specs(cfg: ArchConfig, batch: int, cap: int, kc=None) -> Tree:
     """Cache leaves for ``cap`` positions, stacked per segment. With the
-    kernel config ``kc`` the K/V caches take ``layers.decode_capacity``
+    kernel config ``kc`` the K/V caches take ``attention.decode_capacity``
     slots, the capacity its decode kernel reads with no padding."""
     segs = []
     for (n_rep, cycle) in cfg.pattern_layers():
@@ -149,19 +116,16 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
                  expert_layer=None):
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
-    if kind in ("attn", "attn_dense"):
+    if kind in A.ATTENTION_KINDS:
         h = L.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if cfg.attention == "mla":
-            a_cache = {k: cache[k] for k in ("latent", "pos")} if cache else None
-            a_out, a_cache = L.mla_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
-                                             cache=a_cache, positions=positions,
+            a_out, a_cache = A.mla_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
+                                             cache=cache, positions=positions,
                                              layer=layer)
         else:
-            a_cache = {k: cache[k] for k in ("k", "v", "pos")} if cache else None
-            a_out, a_cache = L.gqa_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
-                                             cache=a_cache, positions=positions,
-                                             window=cfg.local_window if kind == "attn"
-                                             and cfg.block_pattern != ("attn",) else None,
+            a_out, a_cache = A.gqa_attention(p["attn"], h, cfg=cfg, px=px, mode=mode,
+                                             cache=cache, positions=positions,
+                                             window=A.layer_window(cfg, kind),
                                              layer=layer)
         x = x + a_out
         if cache is not None:
@@ -172,10 +136,10 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
             if mode == "decode":
                 ckv = (cache["cross_k"], cache["cross_v"])
             else:
-                ckv = L.cond_kv(p["cross"], cond, cfg=cfg)
+                ckv = A.cond_kv(p["cross"], cond, cfg=cfg)
                 if cache is not None:
                     new_cache["cross_k"], new_cache["cross_v"] = ckv
-            x = x + L.cross_attention(p["cross"], hc, ckv, cfg=cfg, px=px)
+            x = x + A.cross_attention(p["cross"], hc, ckv, cfg=cfg, px=px)
         h2 = L.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
         if "moe" in p and cfg.moe.num_experts_held:
             m_out, copies = L.moe_held(p["moe"], h2, cfg=cfg, px=px,
@@ -213,24 +177,20 @@ def _apply_layer(kind: str, p: Tree, x: jax.Array, *, cfg: ArchConfig,
     return x, new_cache, aux
 
 
-#: cache leaves a decode step writes one token into
-_TOKEN_LEAVES = frozenset({"k", "v", "pos", "latent"})
-
-
 def _layer_cache(stacks: Tree, i) -> Tree:
     """Layer ``i``'s decode cache out of its leaves stacked over the
     segment: the leaves a token is written into are handed whole (the
     attention layer scatters at ``[i, b, slot]``, head-major K/V at
     ``[i, b, :, slot]``), every other leaf
     (recurrent state, cross-attention K/V) is layer ``i``'s slice."""
-    return {n: a if n in _TOKEN_LEAVES
+    return {n: a if n in A.TOKEN_LEAVES
             else lax.dynamic_index_in_dim(a, i, keepdims=False)
             for n, a in stacks.items()}
 
 
 def _write_layer_cache(stacks: Tree, new: Tree, i) -> Tree:
     """``_layer_cache``'s inverse: the stacks with layer ``i``'s update."""
-    return {n: new[n] if n in _TOKEN_LEAVES
+    return {n: new[n] if n in A.TOKEN_LEAVES
             else lax.dynamic_update_index_in_dim(a, new[n], i, 0)
             for n, a in stacks.items()}
 

@@ -9,7 +9,7 @@ full model:
   * parameter counts (for 6ND roofline math).
 
 The spec tree and the runtime param tree share the exact same dict structure;
-``repro.models.layers`` indexes both identically.
+``repro.models.layers`` and ``repro.models.attention`` index both identically.
 """
 from __future__ import annotations
 

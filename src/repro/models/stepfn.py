@@ -3,14 +3,10 @@
 These are the functions the launcher jits and the dry-run lowers. They are
 pure; distribution comes from input shardings + internal constraints.
 
-Kernel dispatch (DESIGN.md §14): when ``px.pcfg.kernel`` is set, the
-attention layers these steps trace route train/prefill attention through
-the tuned Pallas flash kernel (``models/layers.py::_pallas_flash_ok``
-gates it statically, so the choice is baked into the jitted step — a
-kernel hot-swap means re-deriving the step fns, which
-``launch/serve.py::DecodeServer`` memoizes in its compiled-kernel cache).
-With ``kernel=None`` (the default) every path is pure-JAX and
-byte-identical to pre-§14 traces.
+Kernel dispatch (DESIGN.md §14): ``models/attention.py`` picks each
+attention's core from ``px.pcfg`` as a step is traced, so a kernel
+hot-swap re-derives the step fns (``launch/serve.py::DecodeServer``
+memoizes them); ``kernel=None`` (the default) keeps every path pure JAX.
 """
 from __future__ import annotations
 
@@ -134,7 +130,7 @@ def make_prefill_step(cfg: ArchConfig, px: ShardCtx, cache_cap: int):
     """prefill_step(params, batch) -> (last_token_logits, cache).
 
     The cache holds ``cache_cap`` positions, its K/V at the capacity the
-    decode kernel reads with no padding (``layers.decode_capacity``).
+    decode kernel reads with no padding (``attention.decode_capacity``).
     """
 
     def prefill_step(params, batch):

@@ -135,15 +135,15 @@ def test_decode_vmem_model_counts_every_kv_head(KV, G, block_kv, admitted):
     assert ops.decode_valid({"block_kv": block_kv}, KV, G, 128, 2) == admitted
 
 
-# -- GQA-expanded flash dispatch vs the models/layers reference -------------
+# -- GQA-expanded flash dispatch vs the models/attention reference ----------
 
 @pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (4, 1)])   # MHA, GQA, MQA
 @pytest.mark.parametrize("bq,bkv", [(64, 64), (128, 64), (64, 128)])
 def test_flash_gqa_expanded_vs_layers_reference(H, KV, bq, bkv):
     """The serve dispatch path (_pallas_flash_attention) expands KV heads
     and calls the MHA-core Pallas kernel; it must match the grouped-head
-    pure-JAX attention in models/layers.py on causal prefill shapes."""
-    from repro.models.layers import _direct_attention
+    pure-JAX attention in models/attention.py on causal prefill shapes."""
+    from repro.models.attention import _direct_attention
     rng = np.random.default_rng(11)
     B, S, hd = 1, 256, 32
     q = jnp.asarray(rng.normal(size=(B, S, H, hd)), jnp.float32)
@@ -302,10 +302,10 @@ def _decode_case(B, S, H, KV, hd, cur, *, window=None, rolling=False, seed=0,
 
 
 def _check_decode(q, k, v, cp, cu, *, window, layers, **blocks):
-    """The kernel against ``layers._decode_attention``: on the one layer's
+    """The kernel against ``attention._decode_attention``: on the one layer's
     cache, or on each of ``layers`` layers read out of the stack by a
     traced layer index."""
-    from repro.models.layers import _decode_attention
+    from repro.models.attention import _decode_attention
     scale = 1.0 / np.sqrt(q.shape[-1])
     for i in ([None] if layers is None else range(layers)):
         kc, vc = (k, v) if i is None else (k[i], v[i])
@@ -324,7 +324,7 @@ def _check_decode(q, k, v, cp, cu, *, window, layers, **blocks):
                          [(1, 64, "jax"), (2, 32, "jax"), (4, 16, "kernel")])
 def test_decode_parity_gqa_and_splits(H, KV, num_splits, block_kv, combine,
                                       layers):
-    """Kernel output must match the layers.py pure-JAX decode reference
+    """Kernel output must match the attention.py pure-JAX decode reference
     across GQA head ratios and split/combine configurations, on one
     layer's cache and on each layer of a stacked one."""
     q, k, v, cp, cu = _decode_case(2, 128, H, KV, 16, cur=97, layers=layers)
@@ -371,16 +371,16 @@ def test_head_major_cache_write_then_read(KV, window):
     ``[layer, b, h, slot(p)]``, and nothing into the other layers; the
     Pallas kernel and ``_decode_attention`` read that stack as attention
     over the positions the token may see."""
-    from repro.models.layers import (_cache_slot, _decode_attention,
-                                     _insert_slot, _prefill_cache)
+    from repro.models.attention import (_cache_slot, _decode_attention,
+                                        _insert_slot, _prefill_cache)
     rng = np.random.default_rng(KV)
     B, H, hd, S, L, layer = 2, 8, 16, 40, 3, 1
     cap = 64 if window is None else window
     k_seq, v_seq = (jnp.asarray(rng.normal(size=(B, S + 1, KV, hd)),
                                 jnp.float32) for _ in range(2))
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    cache = _prefill_cache(None, k_seq[:, :S], v_seq[:, :S], positions,
-                           cap, window)
+    cache = _prefill_cache(k_seq[:, :S], v_seq[:, :S], positions, cap,
+                           window)
     stacks = {n: jnp.zeros((L,) + a.shape, a.dtype).at[layer].set(a)
               for n, a in cache.items()}
     tok = jnp.full((B, 1), S, jnp.int32)
@@ -424,7 +424,9 @@ def test_head_major_cache_write_then_read(KV, window):
 
 def _golden_decode_run(arch, kernel=None):
     """The exact run tests/golden/decode_logits.json was captured with
-    (pre-PR code, ParallelConfig(kernel=None)); returns final-step logits."""
+    (pre-PR code, ParallelConfig(kernel=None)); returns final-step logits.
+    The weights and prompts are drawn with the PRNG that capture used:
+    threefry not partitionable, the default before JAX 0.5."""
     from repro.configs.registry import smoke_config
     from repro.models.params import init_params
     from repro.models.stepfn import make_decode_step, make_prefill_step
@@ -432,10 +434,11 @@ def _golden_decode_run(arch, kernel=None):
     cfg = smoke_config(arch)
     px = ShardCtx(None, ParallelConfig(flash_threshold=1 << 30,
                                        logits_chunk=0, kernel=kernel))
-    params = init_params(cfg, jax.random.PRNGKey(0))
     B, S, STEPS = 2, 8, 12
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
-                                cfg.vocab_size)
+    with jax.threefry_partitionable(False):
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                                    cfg.vocab_size)
     prefill = jax.jit(make_prefill_step(cfg, px, cache_cap=S + STEPS))
     decode = jax.jit(make_decode_step(cfg, px))
     logits, cache = prefill(params, {"tokens": tokens})

@@ -14,6 +14,7 @@ import pytest
 from repro.configs.arch import YaRNConfig
 from repro.configs.registry import get_arch, smoke_config
 from repro.kernels import ops
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.params import init_params
 from repro.models.stepfn import make_prefill_step
@@ -101,8 +102,8 @@ def test_yarn_tables_follow_the_published_formulas(dim, factor):
 def test_mla_softmax_scale_carries_yarns_mscale_squared():
     cfg = get_arch("deepseek-v3-671b")
     m = 0.1 * math.log(40) + 1.0
-    assert L.mla_softmax_scale(cfg) == pytest.approx(m * m / math.sqrt(192))
-    assert L.mla_softmax_scale(cfg.replace(rope_scaling=None)) == \
+    assert A.mla_softmax_scale(cfg) == pytest.approx(m * m / math.sqrt(192))
+    assert A.mla_softmax_scale(cfg.replace(rope_scaling=None)) == \
         pytest.approx(1 / math.sqrt(192))
 
 
@@ -179,14 +180,12 @@ def test_latent_kernel_matches_the_absorbed_decode(S, block_kv, num_splits,
     pos = jnp.where(jnp.arange(S)[None] < jnp.array([[37], [S - 3]]),
                     jnp.arange(S)[None], -1)
     cur = jnp.array([36, S - 4])
-    kc = KernelConfig(use_decode=True, decode_block_kv=block_kv,
-                      decode_num_splits=num_splits, decode_combine=combine,
-                      interpret=True)
     for layer in (0, 2):
-        want = L._mla_latent_decode(q, lat, pos, cur, r=r, scale=0.3,
-                                    kc=None, layer=layer)
-        got = L._mla_latent_decode(q, lat, pos, cur, r=r, scale=0.3, kc=kc,
-                                   layer=layer)
+        want = A._mla_latent_decode(q, lat, pos, cur, r=r, scale=0.3,
+                                    layer=layer)
+        got = ops.mla_decode_attention(
+            q, lat, pos, cur, layer, v_width=r, scale=0.3, block_kv=block_kv,
+            num_splits=num_splits, combine=combine, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
     alone = ops.mla_decode_attention(q, lat[1], pos, cur, v_width=r,
@@ -194,20 +193,23 @@ def test_latent_kernel_matches_the_absorbed_decode(S, block_kv, num_splits,
                                      num_splits=num_splits, combine=combine,
                                      interpret=True)
     np.testing.assert_allclose(
-        np.asarray(alone), np.asarray(L._mla_latent_decode(
-            q, lat, pos, cur, r=r, scale=0.3, kc=None, layer=1)),
+        np.asarray(alone), np.asarray(A._mla_latent_decode(
+            q, lat, pos, cur, r=r, scale=0.3, layer=1)),
         rtol=2e-5, atol=2e-5)
 
 
 def test_decode_gate_and_capacity_take_the_latent_tile():
+    cfg = get_arch("deepseek-v3-671b")
     kc = KernelConfig(use_decode=True, decode_block_kv=512,
                       decode_num_splits=1)
-    assert L._pallas_decode_ok(640, 512, kc, latent=True)
-    assert not L._pallas_decode_ok(640, 512, kc)
-    assert not L._pallas_decode_ok(640, 512, None, latent=True)
-    assert L.decode_capacity(2560, 640, kc) == 2560
-    assert L.decode_capacity(2561, 640, kc) == 3072
-    assert L.mla_latent_width(get_arch("deepseek-v3-671b").mla) == 640
+    assert A.decode_path(cfg, ParallelConfig(kernel=kc)) == "pallas"
+    assert A.decode_path(cfg, ParallelConfig(kernel=kc.replace(
+        use_decode=False))) == "jax"
+    assert A.decode_path(cfg, ParallelConfig(kernel=None)) == "jax"
+    assert A.decode_capacity(cfg, 2560, kc) == 2560
+    assert A.decode_capacity(cfg, 2561, kc) == 3072
+    assert A.decode_capacity(cfg, 2561, None) == 2561
+    assert A.mla_latent_width(cfg.mla) == 640
 
 
 # -- the blockwise scan's scores bounded by query chunks ---------------------
@@ -234,7 +236,7 @@ def test_prefill_past_the_score_bound_runs_query_chunks_to_the_same_result(
                 str(jax.make_jaxpr(step)(params, batch)).count("scan["))
     whole, scans = run()
     # 4 rows x 4 heads x 4 queries x 4 keys x 4 bytes: four chunks of 4
-    monkeypatch.setattr(L, "SCORE_BLOCK_BYTES", 4 * 4 * 4 * 4 * 4)
+    monkeypatch.setattr(A, "SCORE_BLOCK_BYTES", 4 * 4 * 4 * 4 * 4)
     chunked, chunked_scans = run()
     assert chunked_scans > scans
     for x, y in zip(jax.tree.leaves(whole), jax.tree.leaves(chunked)):
